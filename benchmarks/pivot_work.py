@@ -63,7 +63,7 @@ optimality, an unchanged objective, and warm frontiers beating cold
 (ratio < 1.0 hard, plus the baseline-relative bound).
 
 A ``pallas_workloads`` section A/Bs the Pallas tile kernels
-(src/repro/kernels/, interpret=True on this CPU environment) against their
+(src/repro/kernels/, run by the Pallas interpreter on a CPU) against their
 JAX engines on small mixed batches: the tableau and revised kernels must
 reproduce engine statuses *and* iteration counts exactly (they execute the
 same pivot sequences), the PDHG kernel to tolerance; each kernel also runs

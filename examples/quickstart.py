@@ -116,7 +116,8 @@ big = random_lp_batch(rng, B=10_000, m=10, n=10)
 res = solve_batched(big)                      # pure-JAX lockstep backend
 print(f"10k LPs (jax):    {res.summary()}")
 
-# 3) same batch through the Pallas TPU kernel (interpret=True on CPU)
+# 3) same batch through the Pallas TPU kernel (compiled on TPU, interpreted
+#    on CPU)
 res_k = solve_batched(big, solver=solve_batched_pallas, chunk_size=4096)
 print(f"10k LPs (pallas): {res_k.summary()}")
 

@@ -21,7 +21,7 @@
 # oracle's lanes on the exact engines), and a compacted+traced solve
 # exporting a valid Perfetto span tree.  The full legs start
 # with a pallas smoke block: the revised tile kernel and the PDHG segment
-# kernel (interpret=True) against their JAX engines — pivot-exactness for
+# kernel (interpreted on the CPU) against their JAX engines — pivot-exactness for
 # the simplex kernel, tolerance agreement plus a completed bucket shrink
 # for PDHG under the compaction scheduler.
 #
@@ -180,7 +180,7 @@ pallas_smoke() {
   echo "== pallas kernel smoke =="
   python - <<'EOF'
 # both new tile kernels against their JAX engines on a tiny mixed batch
-# (interpret=True — the Pallas interpreter, ~a minute): the revised kernel
+# (the Pallas interpreter on the CPU, ~a minute): the revised kernel
 # must be pivot-exact, the PDHG segment kernel must agree to tolerance and
 # complete at least one bucket shrink through the compaction scheduler
 import numpy as np

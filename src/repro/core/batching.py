@@ -4,7 +4,9 @@ The paper sizes batches against GPU global memory (``N = floor(S / Y)``,
 Eq. 5) and overlaps H2D/D2H copies with kernel execution via CUDA streams
 (Sec. 5.4). Here:
 
-* the memory budget is HBM bytes per device x device count,
+* the memory budget is the HBM limit each device reports
+  (``memory_stats()["bytes_limit"]``) x device count, spent per LP by the
+  *compiled* program's footprint, not by one tableau,
 * chunk *k+1* is `jax.device_put` (H2D DMA) while chunk *k*'s solve is still
   in flight — JAX's async dispatch gives the CUDA-streams pipeline for free:
   we enqueue transfer->solve per chunk and only block when gathering results
@@ -28,18 +30,40 @@ from .lp import (LPBatch, LPResult, WarmStart, canonicalize_backend,
                  resolve_backend)
 from .simplex import solve_batched_jax
 
-# Conservative default budget for planning on real devices; on CPU hosts this
-# is only used for chunk-size arithmetic, mirroring Eq. (5).
-DEFAULT_DEVICE_BYTES = 16 * 2 ** 30  # one v5e chip's HBM
-# Fraction of the budget the tableaux may claim (leave room for XLA scratch).
+# Fraction of the device limit one chunk's program may claim: the rest
+# holds the next chunk's inputs in flight and allocator slack.
 BUDGET_FRACTION = 0.6
+# Compiled device bytes per LP over LPBatch.bytes_per_lp (one tableau), per
+# engine: the largest ratio memory_analysis() reports for the monolithic
+# programs compiled for a v5e at 28x28 and 100x100 with B from 2048 to
+# 100,000 (tableau 6.6, revised 9.8, pdhg 1.3), rounded up.  The loop
+# carries two tableaux, phase compaction a third, and the ratio test
+# (B, m, C) temporaries; tests/test_tpu_compile.py checks a planned chunk.
+PROGRAM_BYTES_FACTOR = {"tableau": 8, "revised": 10, "pdhg": 2}
 
 
-def max_chunk_size(batch: LPBatch, device_bytes: int = DEFAULT_DEVICE_BYTES,
-                   n_devices: int = 1, dtype_size: int = 4) -> int:
-    """Paper Eq. (5): N = floor(S / Y), with S = usable device bytes."""
+def device_memory_bytes(device=None) -> Optional[int]:
+    """The HBM limit the device reports (``memory_stats()["bytes_limit"]``).
+    None where the backend reports no limit (the CPU, whose host memory is
+    not planned); a TPU that reports none is an error, never a guess."""
+    device = device or jax.devices()[0]
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if limit is None:
+        if device.platform == "tpu":
+            raise RuntimeError(
+                f"{device.device_kind} reports no memory_stats()"
+                "['bytes_limit']; pass device_bytes= to plan chunks")
+        return None
+    return int(limit)
+
+
+def max_chunk_size(batch: LPBatch, device_bytes: int, n_devices: int = 1,
+                   dtype_size: int = 4, backend: str = "tableau") -> int:
+    """Paper Eq. (5): N = floor(S / Y), with S = usable device bytes and
+    Y = the engine's compiled bytes per LP."""
     usable = int(device_bytes * BUDGET_FRACTION) * n_devices
-    per_lp = batch.bytes_per_lp(dtype_size)
+    per_lp = batch.bytes_per_lp(dtype_size) * PROGRAM_BYTES_FACTOR[
+        canonicalize_backend(backend)]
     return max(1, usable // per_lp)
 
 
@@ -65,7 +89,7 @@ def difficulty_proxy(batch: LPBatch) -> np.ndarray:
 
 def solve_batched(batch: LPBatch, *, solver: Optional[Callable] = None,
                   chunk_size: Optional[int] = None,
-                  device_bytes: int = DEFAULT_DEVICE_BYTES,
+                  device_bytes: Optional[int] = None,
                   n_devices: int = 1, sort_by_difficulty: bool = False,
                   compaction: bool = False, pricing: str = "dantzig",
                   backend: str = "tableau",
@@ -102,6 +126,11 @@ def solve_batched(batch: LPBatch, *, solver: Optional[Callable] = None,
     ``solver=None`` it picks the matching compacted/monolithic solver, and a
     custom ``solver`` must accept a ``backend`` kwarg when "revised" is
     requested (solve_batched_pallas does).
+
+    ``chunk_size=None`` plans chunks from ``device_bytes`` (default: the
+    limit the first device reports, `device_memory_bytes`) and the
+    engine's compiled bytes per LP; a batch that does not fit is split into
+    equal chunks, so every chunk runs one compiled program.
 
     A ``GeneralLPBatch`` (core/forms.py) is canonicalized *once* up front —
     chunking, sorting and memory planning all operate on the canonical
@@ -212,7 +241,12 @@ def solve_batched(batch: LPBatch, *, solver: Optional[Callable] = None,
         return solver(sub, **solver_kwargs)
 
     if chunk_size is None:
-        chunk_size = max_chunk_size(batch, device_bytes, n_devices)
+        if device_bytes is None:
+            device_bytes = device_memory_bytes()
+        chunk_size = B if device_bytes is None else max_chunk_size(
+            batch, device_bytes, n_devices, backend=backend)
+        if chunk_size < B:
+            chunk_size = -(-B // -(-B // chunk_size))
     if chunk_size >= B:
         res = call(batch, warm)
         return finish_result(rec, _unpermute(_unpad(res, unpad_B), perm))

@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..obs.report import report_from_counters
 from ..obs.telemetry import tel_to_numpy
@@ -121,14 +120,18 @@ def _backend_defaults(backend: str, max_iters, tol, m: int, n: int, dtype):
 
 
 
-def _prep(batch: LPBatch, mesh: Mesh, dtype):
+def shard_batch(batch: LPBatch, mesh: Mesh, dtype):
+    """Pad the batch to a multiple of the device count and place its
+    (A, b, c, ub) arrays batch-sharded over every mesh axis, so each device
+    receives only its own LPs.  Returns ``(A, b, c, ub, axes, orig_B,
+    padded)``."""
     axes = tuple(mesh.axis_names)
     n_dev = int(np.prod(mesh.devices.shape))
     padded, orig = _pad_batch(batch, n_dev)
-    A = jnp.asarray(padded.A, dtype)
-    b = jnp.asarray(padded.b, dtype)
-    c = jnp.asarray(padded.c, dtype)
-    ub = jnp.asarray(padded.upper_bounds(), dtype)
+    shard = NamedSharding(mesh, P(axes))
+    A, b, c, ub = (jax.device_put(np.asarray(v, dtype), shard)
+                   for v in (padded.A, padded.b, padded.c,
+                             padded.upper_bounds()))
     return A, b, c, ub, axes, orig, padded
 
 
@@ -152,7 +155,7 @@ def solve_pjit(batch: LPBatch, mesh: Mesh, *, dtype=jnp.float32,
     batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale)
     m, n = batch.m, batch.n
     max_iters, tol = _backend_defaults(backend, max_iters, tol, m, n, dtype)
-    A, b, c, ub, axes, orig, _ = _prep(batch, mesh, dtype)
+    A, b, c, ub, axes, orig, _ = shard_batch(batch, mesh, dtype)
     spec = P(axes)  # batch dim sharded over every axis
     shard = NamedSharding(mesh, spec)
     fn = jax.jit(
@@ -215,11 +218,11 @@ class _ShardMapBackend(JaxBackend):
             return state, it.reshape(1)
 
         def wrap(fn):
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 fn, mesh=mesh,
                 in_specs=(state_specs, P()),
                 out_specs=(state_specs, spec),
-                check_rep=False,
+                check_vma=False,
             ))
 
         self._p1 = wrap(p1)
@@ -266,11 +269,11 @@ class _RevisedShardMapBackend(RevisedBackend):
             return state, it.reshape(1)
 
         def wrap(fn):
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 fn, mesh=mesh,
                 in_specs=(state_specs, P()),
                 out_specs=(state_specs, spec),
-                check_rep=False,
+                check_vma=False,
             ))
 
         self._p1 = wrap(p1)
@@ -307,11 +310,11 @@ class _PdhgShardMapBackend(PdhgBackend):
                                      check_every=ce)
             return state, it.reshape(1)
 
-        self._p2 = jax.jit(shard_map(
+        self._p2 = jax.jit(jax.shard_map(
             p2, mesh=mesh,
             in_specs=(state_specs, P()),
             out_specs=(state_specs, spec),
-            check_rep=False,
+            check_vma=False,
         ))
 
     def run_phase2(self, state, steps):
@@ -392,19 +395,19 @@ def solve_shard_map(batch: LPBatch, mesh: Mesh, *, dtype=jnp.float32,
                                                stats_out=stats_out,
                                                tracer=tracer))
 
-    A, b, c, ub, axes, orig, _ = _prep(batch, mesh, dtype)
+    A, b, c, ub, axes, orig, _ = shard_batch(batch, mesh, dtype)
     spec = P(axes)
 
     local = functools.partial(_solve_local, m=m, n=n, max_iters=max_iters,
                               tol=tol, feas_tol=feas_tol, pricing=pricing,
                               backend=backend, refactor_period=refactor_period,
                               telemetry=telemetry)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec, spec, spec, spec),
         # one extra batch-sharded prefix entry covers every telemetry lane
         out_specs=(spec,) * (7 if telemetry else 6),
-        check_rep=False,
+        check_vma=False,
     ))
     if lower_only:
         return fn.lower(jax.ShapeDtypeStruct(A.shape, A.dtype),

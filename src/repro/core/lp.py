@@ -157,6 +157,15 @@ STATUS_NAMES = {
 # with a conditional.
 BIG = 1e30
 
+# Rank-1 pivot-update entries that cancel to within this many rounding
+# units of their operands are set to exactly zero (every tableau engine,
+# the float64 oracle included).  Equality pairs (core/forms.py) and
+# degenerate rows rely on exact cancellation; a divide that is not
+# correctly rounded (the TPU's) leaves a few-ulp residue instead, which
+# the ratio test takes for a pivot element (~1e-6 on O(10) entries, above
+# the f32 ``tol``) and the pivot sequence runs off into noise.
+CANCEL_ULPS = 8
+
 
 @dataclasses.dataclass(frozen=True)
 class BackendSpec:

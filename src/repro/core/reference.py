@@ -16,6 +16,7 @@ import numpy as np
 from .forms import ensure_canonical, finish_result, prepare_warm
 from .lp import (
     BIG,
+    CANCEL_ULPS,
     INFEASIBLE,
     ITERATION_LIMIT,
     OPTIMAL,
@@ -177,7 +178,12 @@ def _solve_single(T, basis, n, m, tol, max_iters, rule="dantzig", ub=None,
         pe = T[l, e]
         pivrow = T[l] / pe
         factor = T[:, e].copy()
-        T -= factor[:, None] * pivrow[None, :]
+        prod = factor[:, None] * pivrow[None, :]
+        T_new = T - prod
+        # cancellation residue -> 0, as simplex.rank1_update
+        noise = CANCEL_ULPS * np.finfo(T.dtype).eps * np.maximum(
+            np.abs(T), np.abs(prod))
+        T[...] = np.where(np.abs(T_new) <= noise, 0.0, T_new)
         T[l] = pivrow
         weights = update_weights_np(rule, weights, T, pivrow, pe, e, basis[l],
                                     m=m, n=n)

@@ -79,6 +79,7 @@ from ..obs.telemetry import init_telemetry, tel_simplex_update, tel_to_numpy
 from .forms import ensure_canonical, finish_result, prepare_warm
 from .lp import (
     BIG,
+    CANCEL_ULPS,
     INFEASIBLE,
     ITERATION_LIMIT,
     OPTIMAL,
@@ -277,6 +278,18 @@ def inject_tableau_warm(A, b, c, ub, wb, wfl, *, m: int, n: int,
     return T, basis, phase, wfl & ok[:, None], ok
 
 
+def rank1_update(T, factor, pivrow):
+    """``T - factor (x) pivrow`` per LP ((B, R, C) - (B, R) x (B, C)), with
+    cancellation residue snapped to zero (see ``lp.CANCEL_ULPS``).  Shared by
+    the pure-JAX steps and the Pallas tile kernel so both stay bitwise
+    equal; entries that cancel exactly are zero either way."""
+    prod = factor[:, :, None] * pivrow[:, None, :]
+    T_new = T - prod
+    noise = CANCEL_ULPS * jnp.finfo(T.dtype).eps * jnp.maximum(
+        jnp.abs(T), jnp.abs(prod))
+    return jnp.where(jnp.abs(T_new) <= noise, 0.0, T_new)
+
+
 def _pivot_update(T, w, basis, factor, pivrow_raw, pe, e, l, do_pivot,
                   rows_iota, *, m, n, rule):
     """Rank-1 pivot update shared by both steps: subtract the entering-column
@@ -291,7 +304,7 @@ def _pivot_update(T, w, basis, factor, pivrow_raw, pe, e, l, do_pivot,
     through untouched and the whole computation DCEs away."""
     pe_safe = jnp.where(do_pivot, pe, 1.0)
     pivrow = pivrow_raw / pe_safe[:, None]
-    T_new = T - factor[:, :, None] * pivrow[:, None, :]
+    T_new = rank1_update(T, factor, pivrow)
     is_l = rows_iota[None, :, None] == l[:, None, None]
     T_new = jnp.where(is_l, pivrow[:, None, :], T_new)
     T_out = jnp.where(do_pivot[:, None, None], T_new, T)

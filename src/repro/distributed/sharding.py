@@ -26,7 +26,6 @@ from repro.models.config import ModelConfig
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    # jax 0.4.x: no axis_types kwarg / jax.sharding.AxisType yet
     devices = np.asarray(jax.devices()[: int(np.prod(shape))]).reshape(shape)
     return Mesh(devices, axes)
 

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .tiling import round_up
+
 
 def _hyperbox_kernel(lo_ref, hi_ref, d_ref, out_ref):
     lo = lo_ref[...]
@@ -25,16 +27,12 @@ def _hyperbox_kernel(lo_ref, hi_ref, d_ref, out_ref):
     out_ref[...] = jnp.sum(d * pick, axis=1, keepdims=True)
 
 
-def _round_up(v: int, k: int) -> int:
-    return (v + k - 1) // k * k
-
-
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
-def hyperbox_pallas(lo, hi, d, *, tile_b: int = 256, interpret: bool = True):
+def hyperbox_pallas(lo, hi, d, *, tile_b: int = 256, interpret: bool):
     """lo/hi/d: (B, n) -> (B,) support values."""
     B, n = lo.shape
-    n_pad = _round_up(n, 128)
-    B_pad = _round_up(B, tile_b)
+    n_pad = round_up(n, 128)
+    B_pad = round_up(B, tile_b)
 
     def pad(a, fill=0.0):
         return jnp.pad(a, ((0, B_pad - B), (0, n_pad - n)),

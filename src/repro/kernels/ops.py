@@ -2,8 +2,10 @@
 
 ``solve_batched_pallas`` is a drop-in for core.simplex.solve_batched_jax
 (same LPBatch -> LPResult contract) and is what core.batching dispatches to
-when ``solver=`` is pointed here. ``interpret=True`` executes the kernel body
-on CPU for validation; on a real TPU pass ``interpret=False``.
+when ``solver=`` is pointed here.  Interpret mode is not an option: it
+follows the platform (`default_interpret`) — on a TPU every kernel is
+compiled by Mosaic and a kernel the compiler refuses raises; anywhere else
+the kernel body runs under the Pallas interpreter for validation.
 
 ``compaction=True`` routes the solve through the active-set compaction
 scheduler (core/compaction.py) with Pallas segment kernels: the batch is
@@ -19,19 +21,18 @@ kernel keeps the full cost row resident in VMEM, so block-restricted pricing
 saves nothing — the rule exists for the revised backend's pricing matvec.
 
 ``backend=`` dispatch follows the core/lp.py registry; every registered
-backend now has a real Pallas surface. ``backend="pdhg"`` (core/pdhg.py)
-runs the whole-solve first-order tile kernel (kernels/pdhg_tile.py —
-fused matvec + prox + restart check in VMEM); with ``compaction=True``
+backend has a real Pallas surface. ``backend="pdhg"`` (core/pdhg.py)
+runs the first-order tile kernel (kernels/pdhg_tile.py — fused matvec +
+prox + restart check in VMEM) for the whole round budget; with
+``compaction=True``
 the scheduler's segments run the resumable PDHG *segment* kernel, so
 bucket gathers happen between kernel launches instead of abandoning
 Pallas. ``backend="revised"`` (core/revised.py) runs the revised-simplex
 tile kernel (kernels/revised_tile.py — BTRAN/FTRAN against a
 VMEM-resident basis inverse + eta file, refactorization at segment
 boundaries), monolithic or under the scheduler with refactor-on-gather.
-A backend whose registry entry reports ``supports_pallas=False`` falls
-back to its pure-JAX path with a warning (fired once per process, not
-once per call) so the entry-point contract stays uniform — no registered
-backend currently takes that path.
+A backend whose registry entry reports ``supports_pallas=False`` is
+refused with a ValueError.
 
 ``warm=`` accepts the backend-uniform `WarmStart` carrier: the revised
 kernel injects a parent basis (phase-1 skip / repair, exactly the
@@ -82,7 +83,13 @@ from .revised_tile import (
 from .hyperbox_kernel import hyperbox_pallas
 
 
-# Fallback/degradation warnings fire once per process, not once per call:
+def default_interpret() -> bool:
+    """The one interpret-mode rule of every kernel entry point: compiled on
+    a TPU, interpreted anywhere else (the CPU test and rehearsal runs)."""
+    return jax.default_backend() != "tpu"
+
+
+# Degradation warnings fire once per process, not once per call:
 # batched sweeps dispatch thousands of solves and a per-call warning is pure
 # spam.  Keyed so distinct conditions still each get their one warning.
 _WARNED: set = set()
@@ -143,11 +150,11 @@ class PallasBackend(JaxBackend):
     executed-work accounting stays in logical (unpadded) tableau elements so
     numbers are comparable across backends."""
 
-    def __init__(self, m, n, tol, feas_tol, tile_b, interpret=True,
-                 dtype=jnp.float32, pricing="dantzig"):
+    def __init__(self, m, n, tol, feas_tol, tile_b, dtype=jnp.float32,
+                 pricing="dantzig"):
         super().__init__(m, n, tol, feas_tol, dtype, pricing=pricing)
         self.tile_b = int(tile_b)
-        self.interpret = bool(interpret)
+        self.interpret = default_interpret()
         self.pad_multiple = self.tile_b
 
     def init(self, A, b, c, ub=None, telemetry: bool = False
@@ -217,13 +224,12 @@ class RevisedPallasBackend(RevisedBackend):
     file. Work accounting (`elements_per_step`) is inherited from the
     pure-JAX revised backend — numbers stay comparable across executors."""
 
-    def __init__(self, m, n, tol, feas_tol, tile_b, interpret=True,
-                 dtype=jnp.float32, pricing="dantzig",
-                 refactor_period=None):
+    def __init__(self, m, n, tol, feas_tol, tile_b, dtype=jnp.float32,
+                 pricing="dantzig", refactor_period=None):
         super().__init__(m, n, tol, feas_tol, dtype, pricing=pricing,
                          refactor_period=refactor_period)
         self.tile_b = int(tile_b)
-        self.interpret = bool(interpret)
+        self.interpret = default_interpret()
         self.pad_multiple = self.tile_b
 
     def init(self, A, b, c, ub=None, warm: WarmStart | None = None,
@@ -283,15 +289,15 @@ class PdhgPallasBackend(PdhgBackend):
     restart bookkeeping stay in VMEM between the scheduler's gathers."""
 
     def __init__(self, m, n, tol, dtype, check_every=None, *,
-                 tile_b=None, interpret=True, vmem_budget=8 * 2 ** 20):
+                 tile_b=None):
         from repro.core.pdhg import CHECK_EVERY
         super().__init__(m, n, tol, dtype,
                          check_every=(CHECK_EVERY if check_every is None
                                       else check_every))
         if tile_b is None:
-            tile_b = pick_pdhg_tile_b(m, n, vmem_budget)
+            tile_b = pick_pdhg_tile_b(m, n)
         self.tile_b = int(tile_b)
-        self.interpret = bool(interpret)
+        self.interpret = default_interpret()
         self.pad_multiple = self.tile_b
 
     def init(self, A, b, c, ub=None, warm: WarmStart | None = None,
@@ -323,8 +329,6 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
                          max_iters: Optional[int] = None,
                          tol: Optional[float] = None,
                          feas_tol: float = 1e-5,
-                         vmem_budget: int = 8 * 2 ** 20,
-                         interpret: bool = True,
                          compaction: bool = False,
                          segment_k: Optional[int] = None,
                          compact_threshold: Optional[float] = None,
@@ -351,25 +355,10 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
             "(counters ride the resumable segment kernels); the whole-solve "
             "kernel path returns stats=None")
         telemetry = False
-    spec = backend_spec(backend)
-    if not spec.supports_pallas:
-        # registry-driven fallback for backends without a kernel surface
-        # (none registered today) — the entry-point contract stays uniform
-        _warn_once(
-            f"{backend}-fallback",
-            f"solve_batched_pallas(backend={backend!r}): the registry "
-            f"reports no Pallas {backend} kernel; falling back to the "
-            f"pure-JAX path (see core/lp.py BACKEND_REGISTRY)")
-        from repro.core.lp import resolve_backend
-        kwargs = dict(dtype=dtype, tol=tol, feas_tol=feas_tol,
-                      max_iters=max_iters, pricing=pricing)
-        if compaction:
-            kwargs.update(segment_k=segment_k,
-                          compact_threshold=compact_threshold,
-                          stats_out=stats_out, telemetry=telemetry,
-                          tracer=tracer)
-        return finish_result(rec, resolve_backend(
-            backend, compacted=compaction)(batch, **kwargs))
+    if not backend_spec(backend).supports_pallas:
+        raise ValueError(f"solve_batched_pallas(backend={backend!r}): the "
+                         f"registry reports no Pallas {backend} kernel")
+    interpret = default_interpret()
     if backend == "pdhg":
         from repro.core.pdhg import _check_pdhg_pricing
         _check_pdhg_pricing(pricing)
@@ -377,9 +366,7 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
             # the scheduler's segments run the resumable PDHG segment
             # kernel; bucket gathers happen between kernel launches
             from repro.core.pdhg import solve_batched_pdhg_compacted
-            runner = functools.partial(PdhgPallasBackend, tile_b=tile_b,
-                                       interpret=interpret,
-                                       vmem_budget=vmem_budget)
+            runner = functools.partial(PdhgPallasBackend, tile_b=tile_b)
             return finish_result(rec, solve_batched_pdhg_compacted(
                 batch, dtype=dtype, tol=tol, max_iters=max_iters,
                 segment_k=segment_k, compact_threshold=compact_threshold,
@@ -398,7 +385,7 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
         if max_iters is None:
             max_iters = default_pdhg_max_iters(m, n)
         if tile_b is None:
-            tile_b = pick_pdhg_tile_b(m, n, vmem_budget)
+            tile_b = pick_pdhg_tile_b(m, n)
         x, obj, status, iters, y, z = pdhg_pallas(
             jnp.asarray(batch.A, dtype), jnp.asarray(batch.b, dtype),
             jnp.asarray(batch.c, dtype),
@@ -416,7 +403,7 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
         if max_iters is None:
             max_iters = default_max_iters(m, n)
         if tile_b is None:
-            tile_b = pick_revised_tile_b(m, n, vmem_budget,
+            tile_b = pick_revised_tile_b(m, n,
                                          refactor_period=refactor_period)
         A = jnp.asarray(batch.A, dtype)
         b = jnp.asarray(batch.b, dtype)
@@ -426,8 +413,8 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
             if segment_k is None:
                 segment_k = auto_segment_k(m, n)
             runner = RevisedPallasBackend(
-                m, n, tol, feas_tol, tile_b, interpret=interpret,
-                dtype=dtype, pricing=rule, refactor_period=refactor_period)
+                m, n, tol, feas_tol, tile_b, dtype=dtype, pricing=rule,
+                refactor_period=refactor_period)
             B = batch.batch
             with _maybe_span(tracer, "dispatch", backend="revised-pallas",
                              B=B, m=m, n=n):
@@ -478,7 +465,7 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
     if tol is None:
         tol = 1e-6 if dtype == jnp.float32 else 1e-9
     if tile_b is None:
-        tile_b = pick_tile_b(m, n, vmem_budget)
+        tile_b = pick_tile_b(m, n)
     if max_iters is None:
         max_iters = default_max_iters(m, n)
     if segment_k is None:
@@ -489,8 +476,7 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
     ub = jnp.asarray(batch.upper_bounds(), dtype)
 
     if compaction:
-        runner = PallasBackend(m, n, tol, feas_tol, tile_b,
-                               interpret=interpret, dtype=dtype,
+        runner = PallasBackend(m, n, tol, feas_tol, tile_b, dtype=dtype,
                                pricing=pricing)
         B = batch.batch
         with _maybe_span(tracer, "dispatch", backend="tableau-pallas",
@@ -518,10 +504,9 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
     return finish_result(rec, res)
 
 
-def solve_hyperbox_pallas(lo, hi, d, *, tile_b: int = 256,
-                          interpret: bool = True) -> np.ndarray:
+def solve_hyperbox_pallas(lo, hi, d, *, tile_b: int = 256) -> np.ndarray:
     out = hyperbox_pallas(jnp.asarray(lo, jnp.float32),
                           jnp.asarray(hi, jnp.float32),
                           jnp.asarray(d, jnp.float32),
-                          tile_b=tile_b, interpret=interpret)
+                          tile_b=tile_b, interpret=default_interpret())
     return np.asarray(out)

@@ -37,7 +37,7 @@ relaxation/projection — all mirroring core/pdhg.py exactly.  Zero padding
 is inert by construction: padded rows/columns have A = 0, b = 0, c = 0
 and unit scales, so iterates, residuals and Farkas certificates never see
 them; padded batch slots are all-zero LPs that converge on their first
-check.  Validated under ``interpret=True`` like the simplex tiles.
+check.
 """
 from __future__ import annotations
 
@@ -63,30 +63,27 @@ from repro.core.pdhg import (
     RESTART_SUFFICIENT,
     init_pdhg_state,
 )
+from .tiling import VMEM_LIMIT_BYTES, compiler_params, pick_tile, round_up
 
 _RUNNING = -1
-
-
-def _round_up(v: int, k: int) -> int:
-    return (v + k - 1) // k * k
 
 
 def pdhg_dims(m: int, n: int):
     """(M, N) of the padded tile: rows to a sublane multiple, the minor
     (lane) axis to 128."""
-    return _round_up(m, 8), _round_up(n, 128)
+    return round_up(m, 8), round_up(n, 128)
 
 
-def pick_pdhg_tile_b(m: int, n: int, vmem_budget: int = 8 * 2 ** 20,
+def pick_pdhg_tile_b(m: int, n: int, vmem_budget: int = VMEM_LIMIT_BYTES,
                      dtype_size: int = 4) -> int:
-    """Tile batch so the working set fits VMEM: the (M, N) data block plus
-    ~8 length-N and ~8 length-M live vectors per LP."""
+    """Tile batch so the working set fits VMEM: the double-buffered (M, N)
+    data block plus ~4 live full-block matvec temporaries, and ~24 length-N
+    and ~24 length-M iterate/state rows in and out (each lane-padded to
+    128)."""
     M, N = pdhg_dims(m, n)
-    per_lp = (M * N + 8 * N + 8 * M + 16) * dtype_size
-    tile = max(1, vmem_budget // per_lp)
-    if tile >= 8:
-        tile = tile // 8 * 8
-    return max(1, min(tile, 512))
+    block = M * N * dtype_size
+    per_lp = 6 * block + 4 * (24 * N + 24 * max(M, 128)) * dtype_size
+    return pick_tile(per_lp, block, vmem_budget)
 
 
 def _mv(A, x):
@@ -165,7 +162,7 @@ def _make_pdhg_round(A, b, c, r, s, eta, binf, cinf, ub, *, tol: float,
 
         x, y, xs, ys, cnt = jax.lax.fori_loop(
             0, check_every, step, (x, y, xs, ys, cnt))
-        iters = iters + check_every * active.astype(jnp.int32)
+        iters = jnp.where(active, iters + check_every, iters)
 
         cc = jnp.maximum(cnt, 1.0)
         xa, ya = xs / cc, ys / cc
@@ -265,132 +262,29 @@ def _make_pdhg_round(A, b, c, r, s, eta, binf, cinf, ub, *, tol: float,
     return body
 
 
-def _pdhg_kernel(A_ref, b_ref, c_ref, r_ref, s_ref, eta_ref, om_ref,
-                 binf_ref, cinf_ref, ub_ref,
-                 x_out, obj_out, status_out, iters_out, y_out, z_out,
-                 *, tol: float, max_rounds: int, check_every: int):
-    """Whole-solve kernel: run the shared check round from a cold start
-    until every LP in the tile is terminal or the round budget is spent."""
-    A = A_ref[...]
-    b = b_ref[...]
-    c = c_ref[...]
-    r = r_ref[...]
-    s = s_ref[...]
-    eta = eta_ref[...]          # (tile_b, 1)
-    om0 = om_ref[...]
-    binf = binf_ref[...]
-    cinf = cinf_ref[...]
-    ub = ub_ref[...]            # (tile_b, N) scaled upper bounds, +inf free
-    tile_b, M, N = A.shape
-    dtype = A.dtype
-
-    zeros_n = jnp.zeros((tile_b, N), dtype)
-    zeros_m = jnp.zeros((tile_b, M), dtype)
-    inf1 = jnp.full((tile_b, 1), jnp.inf, dtype)
-
-    body = _make_pdhg_round(A, b, c, r, s, eta, binf, cinf, ub,
-                            tol=tol, check_every=check_every)
-
-    def cond(carry):
-        it = carry[0]
-        status = carry[11]
-        return jnp.any(status == _RUNNING) & (it < max_rounds)
-
-    init = (jnp.int32(0), zeros_n, zeros_m, zeros_n, zeros_m, zeros_n,
-            zeros_m, jnp.zeros((tile_b, 1), dtype), inf1, inf1, om0,
-            jnp.full((tile_b, 1), _RUNNING, jnp.int32),
-            jnp.zeros((tile_b, 1), jnp.int32))
-    (_, x, y, _, _, _, _, _, _, _, _, status, iters) = jax.lax.while_loop(
-        cond, body, init)
-    status = jnp.where(status == _RUNNING, ITERATION_LIMIT, status)
-
-    # extraction in unscaled coordinates (+ NaN masks off-OPTIMAL)
-    opt = status == OPTIMAL
-    obj = jnp.sum(c * x, axis=1, keepdims=True)
-    z = (c - _mtv(A, y)) / s
-    x_out[...] = x * s
-    obj_out[...] = jnp.where(opt, obj, jnp.nan)
-    status_out[...] = status
-    iters_out[...] = iters
-    y_out[...] = jnp.where(opt, y * r, jnp.nan)
-    z_out[...] = jnp.where(opt, z, jnp.nan)
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("m", "n", "tile_b", "max_iters", "tol", "check_every",
                      "interpret"))
 def pdhg_pallas(A, b, c, ub=None, *, m: int, n: int, tile_b: int,
                 max_iters: int, tol: float, check_every: int = CHECK_EVERY,
-                interpret: bool = True):
-    """Solve the batch with the whole-solve PDHG tile kernel.  Returns
-    (x, obj, status, iters, y, z) for the original (unpadded) batch —
-    the same 6-tuple contract as every solve body.  ``ub`` is an optional
-    (B, n) array of upper bounds (+inf = free above)."""
+                interpret: bool):
+    """Solve the batch with the PDHG tile kernel in one launch: a cold
+    tile state run for the whole round budget by the segment kernel below
+    (the same fused round closure), then the shared extraction epilogue.
+    Returns (x, obj, status, iters, y, z) for the original (unpadded)
+    batch — the same 6-tuple contract as every solve body.  ``ub`` is an
+    optional (B, n) array of upper bounds (+inf = free above)."""
     B = A.shape[0]
-    dtype = A.dtype
     # setup outside the kernel: equilibration + step sizes (jitted JAX)
-    s0 = init_pdhg_state(A, b, c, ub)
-    M, N = pdhg_dims(m, n)
-    B_pad = _round_up(B, tile_b)
-
-    def pad(a, rows, fill=0.0):
-        out = jnp.full((B_pad, rows), fill, dtype)
-        return out.at[:B, :a.shape[1]].set(a)
-
-    Ap = jnp.zeros((B_pad, M, N), dtype).at[:B, :m, :n].set(s0.A)
-    bp = pad(s0.b, M)
-    cp = pad(s0.c, N)
-    rp = pad(s0.rsc, M, 1.0)
-    sp = pad(s0.csc, N, 1.0)
-    etap = pad(s0.eta, 1, 1.0)
-    omp = pad(s0.omega, 1, 1.0)
-    binfp = pad(s0.binf[:, None], 1)
-    cinfp = pad(s0.cinf[:, None], 1)
-    # padded lanes carry +inf (A = c = 0 there, so iterates stay 0 anyway)
-    ubp = pad(s0.ub, N, jnp.inf)
-
-    grid = (B_pad // tile_b,)
+    state = build_pdhg_tile_state(init_pdhg_state(A, b, c, ub), m=m, n=n,
+                                  tile_b=tile_b)
     rounds = -(-int(max_iters) // int(check_every))
-    kernel = functools.partial(_pdhg_kernel, tol=float(tol),
-                               max_rounds=rounds,
-                               check_every=int(check_every))
-    vec = lambda i: (i, 0)  # noqa: E731
-    x, obj, status, iters, y, z = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_b, M, N), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tile_b, M), vec),
-            pl.BlockSpec((tile_b, N), vec),
-            pl.BlockSpec((tile_b, M), vec),
-            pl.BlockSpec((tile_b, N), vec),
-            pl.BlockSpec((tile_b, 1), vec),
-            pl.BlockSpec((tile_b, 1), vec),
-            pl.BlockSpec((tile_b, 1), vec),
-            pl.BlockSpec((tile_b, 1), vec),
-            pl.BlockSpec((tile_b, N), vec),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile_b, N), vec),
-            pl.BlockSpec((tile_b, 1), vec),
-            pl.BlockSpec((tile_b, 1), vec),
-            pl.BlockSpec((tile_b, 1), vec),
-            pl.BlockSpec((tile_b, M), vec),
-            pl.BlockSpec((tile_b, N), vec),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B_pad, N), dtype),
-            jax.ShapeDtypeStruct((B_pad, 1), dtype),
-            jax.ShapeDtypeStruct((B_pad, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B_pad, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B_pad, M), dtype),
-            jax.ShapeDtypeStruct((B_pad, N), dtype),
-        ],
-        interpret=interpret,
-    )(Ap, bp, cp, rp, sp, etap, omp, binfp, cinfp, ubp)
-    return (x[:B, :n], obj[:B, 0], status[:B, 0].astype(jnp.int8),
-            iters[:B, 0], y[:B, :m], z[:B, :n])
+    state, _ = pdhg_segment_pallas(
+        jnp.int32(rounds), state, m=m, n=n, tile_b=tile_b, tol=tol,
+        check_every=check_every, interpret=interpret)
+    x, obj, status, iters, y, z = _extract_pdhg_tile_jit(state, m=m, n=n)
+    return x[:B], obj[:B], status[:B], iters[:B], y[:B], z[:B]
 
 
 # ---------------------------------------------------------------------------
@@ -437,7 +331,7 @@ def build_pdhg_tile_state(s0, *, m: int, n: int, tile_b: int
     B = s0.A.shape[0]
     dtype = s0.A.dtype
     M, N = pdhg_dims(m, n)
-    B_pad = _round_up(B, tile_b)
+    B_pad = round_up(B, tile_b)
 
     def pad(a, rows, fill=0.0):
         out = jnp.full((B_pad, rows), fill, dtype)
@@ -535,7 +429,7 @@ def _pdhg_segment_kernel(steps_ref, A_ref, b_ref, c_ref, r_ref, s_ref,
 def pdhg_segment_pallas(steps, state: PdhgTileState, *, m: int, n: int,
                         tile_b: int, tol: float,
                         check_every: int = CHECK_EVERY,
-                        interpret: bool = True):
+                        interpret: bool):
     """Run up to ``steps`` check rounds per tile and return
     ``(new_state, executed_rounds)`` — the PDHG analogue of the simplex
     ``segment_pallas`` protocol (early exit per tile once every LP in it is
@@ -603,6 +497,7 @@ def pdhg_segment_pallas(steps, state: PdhgTileState, *, m: int, n: int,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=compiler_params(),
     )(*operands)
     (x, y, xs, ys, xr, yr, cnt, last, prev, om, status, iters,
      it) = outs[:13]

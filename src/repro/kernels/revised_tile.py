@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lp import (BIG, INFEASIBLE, ITERATION_LIMIT, OPTIMAL,
                            UNBOUNDED)
@@ -55,10 +56,7 @@ from repro.core.revised import (auto_refactor_period, build_revised_state,
                                 canonicalize_revised_rule,
                                 inject_revised_warm)
 from repro.core.simplex import _RUNNING, scatter_solution
-
-
-def _round_up(v: int, k: int) -> int:
-    return -(-v // k) * k
+from .tiling import VMEM_LIMIT_BYTES, compiler_params, pick_tile, round_up
 
 
 def revised_dims(m: int, n: int):
@@ -66,26 +64,26 @@ def revised_dims(m: int, n: int):
     MC rows (multiple of 8), NC2 lanes over the full column layout
     (structurals | slacks | artificials), NCP lanes over the priced
     candidates (structurals | slacks)."""
-    MC = _round_up(max(m, 1), 8)
-    NC2 = _round_up(n + 2 * m, 128)
-    NCP = _round_up(n + m, 128)
+    MC = round_up(max(m, 1), 8)
+    NC2 = round_up(n + 2 * m, 128)
+    NCP = round_up(n + m, 128)
     return MC, NC2, NCP
 
 
-def pick_revised_tile_b(m: int, n: int, vmem_budget: int = 8 * 2 ** 20,
+def pick_revised_tile_b(m: int, n: int, vmem_budget: int = VMEM_LIMIT_BYTES,
                         refactor_period: int | None = None,
                         dtype_size: int = 4) -> int:
     """Largest batch tile whose VMEM working set fits the budget: the
-    immutable data block, the dense basis inverse, the eta file, the one-hot
-    pricing masks and a handful of lane/row vectors."""
+    double-buffered data block and basis inverse, the eta-file scratch,
+    the one-hot pricing/FTRAN masks and the lane/row vectors (rows of
+    width < 128 are lane-padded to 128)."""
     MC, NC2, NCP = revised_dims(m, n)
+    ML = round_up(MC, 128)
     K = int(refactor_period or auto_refactor_period(m, n))
-    per_lp = (MC * NC2 + MC * MC + 2 * MC * NCP + (K + 2) * MC
-              + 8 * NCP + 10 * MC + 16) * dtype_size
-    tile = max(1, int(vmem_budget) // per_lp)
-    if tile >= 8:
-        tile = (tile // 8) * 8
-    return int(max(1, min(tile, 512)))
+    block = MC * NC2 * dtype_size
+    per_lp = (4 * MC * NC2 + 2 * MC * ML + 2 * MC * NCP + K * ML
+              + 4 * (8 * NCP + 8 * ML)) * dtype_size
+    return pick_tile(per_lp, block, vmem_budget)
 
 
 class RevisedTileState(NamedTuple):
@@ -162,7 +160,7 @@ def _pad_tile_state(Abar, cvec, ub, thr, xB, basis, onub, phase, status,
     B = Abar.shape[0]
     dtype = Abar.dtype
     MC, NC2, NCP = revised_dims(m, n)
-    B_pad = _round_up(max(B, 1), tile_b)
+    B_pad = round_up(max(B, 1), tile_b)
     idx = jnp.arange(m)
     Abar_t = jnp.zeros((B_pad, MC, NC2), dtype).at[:B, :m, :n + 2 * m].set(
         Abar)
@@ -235,6 +233,9 @@ def _revised_segment_kernel(steps_ref, Abar_ref, cvec_ref, ub_ref, thr_ref,
     and every pivot bumps its lanes with the same masks the engine feeds
     ``tel_simplex_update`` / ``tel_revised_update``; the disabled trace is
     byte-identical to the pre-telemetry kernel."""
+    # the eta file lives in two VMEM scratch refs indexed on their leading
+    # axis (Pallas TPU lowers no dynamic_slice of a loop-carried value)
+    *refs, etaR_ref, etaV_ref = refs
     if telemetry:
         ti_ref = refs[0]
         (xB_out, basis_out, onub_out, phase_out, status_out, iters_out,
@@ -256,53 +257,56 @@ def _revised_segment_kernel(steps_ref, Abar_ref, cvec_ref, ub_ref, thr_ref,
 
     row = lax.broadcasted_iota(jnp.int32, (tile_b, MC), 1)
     lane = lax.broadcasted_iota(jnp.int32, (tile_b, NCP), 1)
-    lane2 = lax.broadcasted_iota(jnp.int32, (tile_b, NC2), 1)
+    row3 = lax.broadcasted_iota(jnp.int32, (tile_b, MC, NCP), 1)
+    lane3 = lax.broadcasted_iota(jnp.int32, (tile_b, MC, NC2), 2)
     row_ok = row < m
     col_ok = lane < ncand
     if rule == "partial":
         n_blocks, blk_sz = partial_geometry(ncand)
 
-    def btran(v, etaR, etaV, cnt):
+    def btran(v, cnt):
         # newest eta first, then the dense inverse transposed
         def body(i, v):
             k = cnt - 1 - i
-            r = lax.dynamic_slice(etaR, (0, k), (tile_b, 1))
-            ev = lax.dynamic_slice(etaV, (0, k, 0), (tile_b, 1, MC))[:, 0, :]
+            r = etaR_ref[k]
+            ev = etaV_ref[k]
             dot = jnp.sum(ev * v, axis=1, keepdims=True)
             return jnp.where(row == r, dot, v)
         v = lax.fori_loop(0, cnt, body, v)
         return jnp.sum(Binv * v[:, :, None], axis=1)
 
-    def ftran(a_e, etaR, etaV, cnt):
+    def ftran(a_e, cnt):
         # dense inverse first, then oldest eta first
         u = jnp.sum(Binv * a_e[:, None, :], axis=2)
         def body(k, v):
-            r = lax.dynamic_slice(etaR, (0, k), (tile_b, 1))
-            ev = lax.dynamic_slice(etaV, (0, k, 0), (tile_b, 1, MC))[:, 0, :]
+            r = etaR_ref[k]
+            ev = etaV_ref[k]
             vr = jnp.sum(jnp.where(row == r, v, 0.0), axis=1, keepdims=True)
             upd = ev * vr
             return jnp.where(row == r, upd, v + upd)
         return lax.fori_loop(0, cnt, body, u)
 
     def pivot(carry):
-        (it, xB, basis, onub, phase, status, iters, etaR, etaV, cnt,
-         ti) = carry
+        it, xB, basis, onub, phase, status, iters, cnt, ti = carry
         active = status == _RUNNING
         in_p1 = phase == 1
         in_p2 = phase == 2
 
         # ---- Step 1: BTRAN + pricing --------------------------------------
-        # one-hot basic-lane map over the priced candidates (rows < m only)
-        hitc = (lane[:, None, :] == basis[:, :, None]) & row_ok[:, :, None]
+        # one-hot basic-lane map over the priced candidates (rows < m only);
+        # 3-D masks are built from 3-D iotas and widened int rows — Mosaic
+        # cannot lay out a boolean (tile_b, MC) -> (tile_b, MC, 1) broadcast
+        basis3 = basis[:, :, None]
+        hitc = (lane[:, None, :] == basis3) & (row3 < m)
         basis_c = jnp.sum(jnp.where(hitc, cvec[:, None, :], 0.0), axis=2)
         art = (basis >= ncand) & row_ok
         cB = jnp.where(in_p1, -art.astype(dtype),
                        jnp.where(row_ok, basis_c, 0.0))
-        y = btran(cB, etaR, etaV, cnt)
+        y = btran(cB, cnt)
         yA = jnp.sum(Abar[:, :, :NCP] * y[:, :, None], axis=1)
         d = jnp.where(in_p2, cvec, 0.0) - yA
         d = jnp.where(onub != 0, -d, d)
-        is_basic = jnp.any(hitc & (basis < ncand)[:, :, None], axis=1)
+        is_basic = jnp.any(hitc & (basis3 < ncand), axis=1)
         d_full = jnp.where(col_ok & ~is_basic, d, -BIG)
 
         if rule == "partial":
@@ -330,8 +334,8 @@ def _revised_segment_kernel(steps_ref, Abar_ref, cvec_ref, ub_ref, thr_ref,
         p2_done = active & in_p2 & is_opt
 
         # ---- Step 2: FTRAN + sentinel min-ratio ---------------------------
-        a_e = jnp.sum(jnp.where((lane2 == e)[:, None, :], Abar, 0.0), axis=2)
-        u = ftran(a_e, etaR, etaV, cnt)
+        a_e = jnp.sum(jnp.where(lane3 == e[:, :, None], Abar, 0.0), axis=2)
+        u = ftran(a_e, cnt)
         onub_e = jnp.sum(jnp.where(lane == e, onub, 0), axis=1,
                          keepdims=True) != 0
         dir_e = jnp.where(onub_e, -1.0, 1.0).astype(dtype)
@@ -339,7 +343,7 @@ def _revised_segment_kernel(steps_ref, Abar_ref, cvec_ref, ub_ref, thr_ref,
         valid_row = ucol > tol
         ratios = jnp.where(valid_row,
                            xB / jnp.where(valid_row, ucol, 1.0), BIG)
-        ubB = jnp.min(jnp.where(hitc & (basis < n)[:, :, None],
+        ubB = jnp.min(jnp.where(hitc & (basis3 < n),
                                 ub[:, None, :], jnp.inf), axis=2)
         hit_ub = (ucol < -tol) & jnp.isfinite(ubB)
         ratios = jnp.where(hit_ub,
@@ -384,8 +388,8 @@ def _revised_segment_kernel(steps_ref, Abar_ref, cvec_ref, ub_ref, thr_ref,
         eta = jnp.where(do_pivot, -u / ul_safe, 0.0)
         eta = jnp.where(row == r_eta,
                         jnp.where(do_pivot, 1.0 / ul_safe, 1.0), eta)
-        etaR = lax.dynamic_update_slice(etaR, r_eta, (0, cnt))
-        etaV = lax.dynamic_update_slice(etaV, eta[:, None, :], (0, cnt, 0))
+        etaR_ref[cnt] = r_eta
+        etaV_ref[cnt] = eta
         cnt = cnt + jnp.any(do_pivot).astype(jnp.int32)
 
         basis = jnp.where(do_pivot & is_l, e, basis)
@@ -413,12 +417,10 @@ def _revised_segment_kernel(steps_ref, Abar_ref, cvec_ref, ub_ref, thr_ref,
                               active & ~blk_improving)
         phase = jnp.where(to_phase2, 2, phase)
         iters = iters + inc.astype(jnp.int32)
-        return (it + 1, xB, basis, onub, phase, status, iters,
-                etaR, etaV, cnt, ti)
+        return (it + 1, xB, basis, onub, phase, status, iters, cnt, ti)
 
     def cond(carry):
-        (it, xB, basis, onub, phase, status, iters, etaR, etaV, cnt,
-         ti) = carry
+        it, xB, basis, onub, phase, status, iters, cnt, ti = carry
         if stage == "p1":
             pending = (status == _RUNNING) & (phase == 1)
         else:
@@ -427,11 +429,10 @@ def _revised_segment_kernel(steps_ref, Abar_ref, cvec_ref, ub_ref, thr_ref,
 
     ti0 = ti_ref[...] if telemetry else None
     init = (jnp.int32(0), xB_ref[...], basis_ref[...], onub_ref[...],
-            phase_ref[...], status_ref[...], iters_ref[...],
-            jnp.zeros((tile_b, K), jnp.int32),
-            jnp.zeros((tile_b, K, MC), dtype), jnp.int32(0), ti0)
-    (it, xB, basis, onub, phase, status, iters, _, _, _,
-     ti) = lax.while_loop(cond, pivot, init)
+            phase_ref[...], status_ref[...], iters_ref[...], jnp.int32(0),
+            ti0)
+    it, xB, basis, onub, phase, status, iters, _, ti = lax.while_loop(
+        cond, pivot, init)
 
     xB_out[...] = xB
     basis_out[...] = basis
@@ -451,7 +452,7 @@ def _revised_segment_kernel(steps_ref, Abar_ref, cvec_ref, ub_ref, thr_ref,
 def revised_segment_pallas(steps, Abar, cvec, ub, thr, Binv, xB, basis, onub,
                            phase, status, iters, tel_int=None, *, stage: str,
                            m: int, n: int, tile_b: int, tol: float, K: int,
-                           interpret: bool = True,
+                           interpret: bool,
                            pricing: str = "dantzig"):
     """Run up to ``steps`` revised pivots per tile (stage-aware early exit,
     eta-file boundary at ``K`` pivots).  Returns the mutated state leaves
@@ -509,8 +510,12 @@ def revised_segment_pallas(steps, Abar, cvec, ub, thr, Binv, xB, basis, onub,
         out_shape.append(jax.ShapeDtypeStruct((B, INT_ROW_WIDTH), jnp.int32))
         operands = operands + (tel_int,)
     steps_arr = jnp.full((1, 1), steps, jnp.int32)
+    scratch = [pltpu.VMEM((int(K), tile_b, 1), jnp.int32),    # eta rows
+               pltpu.VMEM((int(K), tile_b, MC), dtype)]       # eta columns
     return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
                           out_specs=out_specs, out_shape=out_shape,
+                          scratch_shapes=scratch,
+                          compiler_params=compiler_params(),
                           interpret=interpret)(steps_arr, *operands)
 
 
@@ -558,7 +563,7 @@ def _extract_revised_tile_jit(state: RevisedTileState, *, m: int, n: int):
 def revised_pallas(A, b, c, ub=None, *, m: int, n: int, tile_b: int,
                    max_iters: int, tol: float, feas_tol: float,
                    refactor_period: int | None = None,
-                   pricing: str = "dantzig", interpret: bool = True,
+                   pricing: str = "dantzig", interpret: bool,
                    warm_basis=None, warm_at_upper=None):
     """Whole-solve entry point: host loop of kernel segments with
     refactorization at every boundary.  Returns the standard 8-tuple

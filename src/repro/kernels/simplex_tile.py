@@ -65,23 +65,27 @@ from jax.experimental import pallas as pl
 
 from repro.core.lp import BIG, INFEASIBLE, ITERATION_LIMIT, OPTIMAL, UNBOUNDED
 from repro.core.pricing import DEVEX_RESET
+from repro.core.simplex import rank1_update
 from repro.obs.telemetry import INT_LANE, INT_ROW_WIDTH, lane_add
+from .tiling import VMEM_LIMIT_BYTES, compiler_params, pick_tile, round_up
 
 _RUNNING = -1
 
 
-def _round_up(v: int, k: int) -> int:
-    return (v + k - 1) // k * k
+def _iota(x, axis: int):
+    """int32 index along ``axis`` broadcast to ``x``'s shape (2-D+ iotas
+    are what Mosaic lowers)."""
+    return jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
 
 
 def compacted_dims(m: int, n: int) -> Tuple[int, int]:
     """(rows, lane-padded cols) of the phase-compacted tile."""
-    return _round_up(m + 1, 8), _round_up(n + m + 1, 128)
+    return round_up(m + 1, 8), round_up(n + m + 1, 128)
 
 
 def full_dims(m: int, n: int) -> Tuple[int, int]:
     """(rows, lane-padded cols) of the full two-phase tile."""
-    return _round_up(m + 2, 8), _round_up(n + 2 * m + 1, 128)
+    return round_up(m + 2, 8), round_up(n + 2 * m + 1, 128)
 
 
 def _tile_min_ratio(T, col_full, row_ids, pin_rows, basis, ub, lane,
@@ -191,10 +195,15 @@ def _tile_pivot(T, basis, w, flip, ub, col_full, row_ids, lane, e, l,
 
     pe_safe = jnp.where(do_pivot, pe, 1.0)
     pivrow = pivrow_raw / pe_safe
-    T_new = T - col_full[:, :, None] * pivrow[:, None, :]
-    # replace (not re-add) the pivot row — matches the NumPy oracle
-    T_new = jnp.where(is_l[:, :, None], pivrow[:, None, :], T_new)
-    T = jnp.where(do_pivot[:, :, None], T_new, T)
+    T_new = rank1_update(T, col_full, pivrow)
+    # replace (not re-add) the pivot row — matches the NumPy oracle.  The
+    # (tile_b, R) and (tile_b, 1) masks are widened to the tile as f32
+    # one-hots and compared there: Mosaic cannot lay out a boolean
+    # (tile_b, R) -> (tile_b, R, 1) broadcast, and a select keeps the
+    # replaced entries bit-exact where a one-hot multiply-add would not
+    T_new = jnp.where(is_l.astype(dtype)[:, :, None] > 0.5,
+                      pivrow[:, None, :], T_new)
+    T = jnp.where(do_pivot.astype(dtype)[:, :, None] > 0.5, T_new, T)
 
     if rule == "steepest_edge":
         con = jnp.where((row_ids < m)[:, :, None], T, 0.0)
@@ -352,27 +361,30 @@ def _compact_tile(T, *, m: int, n: int):
     Works on kernel tile values and on batched host arrays alike."""
     C = T.shape[2]
     R2, C2 = compacted_dims(m, n)
-    T2 = jnp.zeros(T.shape[:1] + (R2, C2), T.dtype)
-    T2 = T2.at[:, :m + 1, :n + m].set(T[:, :m + 1, :n + m])
-    T2 = T2.at[:, :m + 1, C2 - 1].set(T[:, :m + 1, C - 1])
-    return T2
+    # masked select over an aligned leading block (no scatter: Pallas TPU
+    # cannot lower one); the RHS column is moved as a lane one-hot sum
+    rhs = jnp.sum(jnp.where(_iota(T, 2) == C - 1, T, 0.0), axis=2)[:, :R2]
+    T2 = T[:, :R2, :C2]
+    row = _iota(T2, 1)
+    lane = _iota(T2, 2)
+    T2 = jnp.where(lane < n + m, T2, 0.0)
+    T2 = jnp.where(lane == C2 - 1, rhs[:, :, None], T2)
+    return jnp.where(row < m + 1, T2, 0.0)
 
 
 def _compact_tile_weights(w, *, m: int, n: int):
     """Phase compaction of the lane-padded pricing-weight row:
     (B, C) -> (B, C2).  Dropped/pad lanes get weight 1 (never priced —
     they sit outside the ``lane < n+m`` entering mask)."""
-    _, C2 = compacted_dims(m, n)
-    w2 = jnp.ones(w.shape[:1] + (C2,), w.dtype)
-    return w2.at[:, :n + m].set(w[:, :n + m])
+    return _compact_tile_lane(w, 1.0, m=m, n=n)
 
 
 def _compact_tile_lane(v, fill, *, m: int, n: int):
     """Phase compaction of a generic lane row (bound vector: fill=+inf,
     flip parity: fill=0): (B, C) -> (B, C2) keeping the n+m live lanes."""
     _, C2 = compacted_dims(m, n)
-    v2 = jnp.full(v.shape[:1] + (C2,), fill, v.dtype)
-    return v2.at[:, :n + m].set(v[:, :n + m])
+    v2 = v[:, :C2]
+    return jnp.where(_iota(v2, 1) < n + m, v2, jnp.asarray(fill, v.dtype))
 
 
 def _init_tile_weights(T, row_ids, *, m: int, rule: str):
@@ -382,6 +394,15 @@ def _init_tile_weights(T, row_ids, *, m: int, rule: str):
         con = jnp.where((row_ids < m)[:, :, None], T, 0.0)
         return 1.0 + jnp.sum(con * con, axis=1)
     return jnp.ones(T.shape[:1] + (T.shape[2],), T.dtype)
+
+
+def _pad_lanes(v, width: int):
+    """Zero-pad a (tile_b, k) lane row to ``width`` lanes (Mosaic has no
+    zero-size vectors, so an already full row is returned as is)."""
+    if v.shape[1] == width:
+        return v
+    return jnp.concatenate(
+        [v, jnp.zeros((v.shape[0], width - v.shape[1]), v.dtype)], axis=1)
 
 
 def _extract_tile(T2, basis, status, flip, ub, *, m: int, n: int, n_pad: int,
@@ -407,11 +428,8 @@ def _extract_tile(T2, basis, status, flip, ub, *, m: int, n: int, n_pad: int,
     obj = -T2[:, m, C2 - 1][:, None]
     opt = status == OPTIMAL
     obj = jnp.where(opt, obj, jnp.nan)
-    y = jnp.concatenate(
-        [-T2[:, m, n:n + m], jnp.zeros((tile_b, m_pad - m), T2.dtype)],
-        axis=1)
-    z = jnp.concatenate(
-        [T2[:, m, :n], jnp.zeros((tile_b, n_pad - n), T2.dtype)], axis=1)
+    y = _pad_lanes(-T2[:, m, n:n + m], m_pad)
+    z = _pad_lanes(T2[:, m, :n], n_pad)
     z = jnp.where(flip_x, -z, z)
     y = jnp.where(opt, y, jnp.nan)
     z = jnp.where(opt, z, jnp.nan)
@@ -578,7 +596,7 @@ def _segment_kernel(steps_ref, T_ref, basis_ref, w_ref, flip_ref, ub_ref,
                      "pricing"))
 def segment_pallas(steps, T, basis, w, flip, ub, phase, thr, status, iters,
                    tel_int=None, *, stage: str, m: int, n: int, tile_b: int,
-                   tol: float, interpret: bool = True,
+                   tol: float, interpret: bool,
                    pricing: str = "dantzig"):
     """Run one scheduler segment (<= ``steps`` pivots) over all tiles.
     Returns (T, basis, w, flip, phase, status, iters, it) with ``it`` the
@@ -645,23 +663,23 @@ def segment_pallas(steps, T, basis, w, flip, ub, phase, thr, status, iters,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=compiler_params(),
     )(*operands)
 
 
-def pick_tile_b(m: int, n: int, vmem_budget: int = 8 * 2 ** 20,
+def pick_tile_b(m: int, n: int, vmem_budget: int = VMEM_LIMIT_BYTES,
                 dtype_size: int = 4) -> int:
     """Choose the LP-tile batch so the working set fits the VMEM budget —
     the paper's Eq. (5)/(6) block-size limit recast as a VMEM tiling rule
     (and the reason our solver has no 511-dimension hard cap). Sized for
     loop 1 (the full tableau); the compacted loop-2 tile is strictly
-    smaller."""
+    smaller.  Per LP: the tableau block in and out, each double-buffered by
+    the pipeline, ~8 live full-tile temporaries of the pivot step, and the
+    lane rows (kernels/tiling.py)."""
     R, C = full_dims(m, n)
-    # tableau + ~6 (tile_b, C) scratch vectors + basis/ratios
-    per_lp = (R * C + 6 * C + 4 * R) * dtype_size
-    tile = max(1, vmem_budget // per_lp)
-    if tile >= 8:
-        tile = tile // 8 * 8
-    return max(1, min(tile, 512))
+    block = R * C * dtype_size
+    per_lp = 12 * block + 32 * C * dtype_size
+    return pick_tile(per_lp, block, vmem_budget)
 
 
 def build_padded_tableau(A: jax.Array, b: jax.Array, c: jax.Array,
@@ -676,7 +694,7 @@ def build_padded_tableau(A: jax.Array, b: jax.Array, c: jax.Array,
     B, m, n = A.shape
     dtype = A.dtype
     R, C = full_dims(m, n)
-    B_pad = _round_up(B, tile_b)
+    B_pad = round_up(B, tile_b)
 
     neg = b < 0
     sign = jnp.where(neg, -1.0, 1.0).astype(dtype)
@@ -712,7 +730,7 @@ def build_padded_tableau(A: jax.Array, b: jax.Array, c: jax.Array,
                      "interpret", "pricing"))
 def simplex_pallas(A, b, c, ub=None, *, m: int, n: int, tile_b: int,
                    max_iters: int, tol: float = 1e-6, feas_tol: float = 1e-5,
-                   interpret: bool = True, pricing: str = "dantzig"):
+                   interpret: bool, pricing: str = "dantzig"):
     """Solve the batch with the phase-compacted Pallas tile kernel. Returns
     (x, obj, status, iters) for the original (unpadded) batch.  ``pricing``
     selects the entering-column rule (core/pricing.py); ``ub`` adds native
@@ -723,8 +741,8 @@ def simplex_pallas(A, b, c, ub=None, *, m: int, n: int, tile_b: int,
         A, b, c, tile_b, feas_tol=feas_tol, ub=ub)
     B_pad = T.shape[0]
     grid = (B_pad // tile_b,)
-    n_pad = _round_up(n, 128)
-    m_pad = _round_up(m, 8)
+    n_pad = round_up(n, 128)
+    m_pad = round_up(m, 8)
 
     kernel = functools.partial(_simplex_kernel, m=m, n=n, tol=tol,
                                max_iters=max_iters, rule=pricing)
@@ -755,6 +773,7 @@ def simplex_pallas(A, b, c, ub=None, *, m: int, n: int, tile_b: int,
             jax.ShapeDtypeStruct((B_pad, n_pad), A.dtype),
         ],
         interpret=interpret,
+        compiler_params=compiler_params(),
     )(T, basis, phase, thr, ub_lane)
     return (x[:B, :n], obj[:B, 0], status[:B, 0].astype(jnp.int8),
             iters[:B, 0], y[:B, :m], z[:B, :n])

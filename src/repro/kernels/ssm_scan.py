@@ -100,8 +100,7 @@ def _grid_call(kernel, arrays, out_shapes, TB: int, DT: int, interpret: bool):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def ssm_scan(dA, dBx, h0, TB: int = 1, DT: int = 128,
-             interpret: bool = True):
+def ssm_scan(dA, dBx, h0, TB: int, DT: int, interpret: bool):
     """dA, dBx: (B, T, S, D) f32; h0: (B, S, D) f32 ->
     (hs (B, T, S, D), hT (B, S, D))."""
     hs, hT = _ssm_fwd(dA, dBx, h0, TB, DT, interpret)
@@ -139,10 +138,11 @@ def _bwd_rule(TB, DT, interpret, res, cts):
 ssm_scan.defvjp(_fwd_rule, _bwd_rule)
 
 
-def ssm_scan_bt_ds(dA, dBx, h0, *, interpret: bool = True
-                   ) -> Tuple[jax.Array, jax.Array]:
+def ssm_scan_bt_ds(dA, dBx, h0) -> Tuple[jax.Array, jax.Array]:
     """Adapter for mamba's (B, T, d, s) layout -> kernel's (B, T, s, d).
-    Pads channels to a lane multiple. Returns ((B, T, d, s), (B, d, s))."""
+    Pads channels to a lane multiple. Returns ((B, T, d, s), (B, d, s)).
+    Interpret mode follows the platform (kernels.ops.default_interpret)."""
+    from .ops import default_interpret
     B, T, d, s = dA.shape
     DT = 128 if d % 128 == 0 else _round_up(min(d, 128), 8)
     d_pad = _round_up(d, DT)
@@ -156,7 +156,7 @@ def ssm_scan_bt_ds(dA, dBx, h0, *, interpret: bool = True
         return x
 
     hs, hT = ssm_scan(prep(dA, True), prep(dBx, True), prep(h0, False),
-                      1, DT, interpret)
+                      1, DT, default_interpret())
     hs = jnp.moveaxis(hs, -1, -2)[..., :d, :]
     hT = jnp.moveaxis(hT, -1, -2)[..., :d, :]
     return hs, hT

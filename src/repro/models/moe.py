@@ -30,7 +30,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 
 from .config import ModelConfig
 from .layers import dense_init
@@ -153,11 +152,11 @@ def moe_apply(p, x, cfg: ModelConfig, shd=None):
                              tp_axis=tp_axis)
             return out.reshape(Bl, Sl, Dl)
 
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(x_spec, w_spec),
             out_specs=x_spec,
-            check_rep=False,
+            check_vma=False,
         )(x, routed_p)
     else:
         out = _moe_local(x.reshape(B * S, D), routed_p, cfg, tp=1,

@@ -1,4 +1,4 @@
-"""Pallas kernel sweeps (interpret=True) vs the pure-jnp oracle."""
+"""Pallas kernel sweeps (interpreted on the CPU) vs the pure-jnp oracle."""
 import numpy as np
 import pytest
 

@@ -84,6 +84,74 @@ def test_memory_planning_eq5():
         == 2 * max_chunk_size(batch, device_bytes=1 << 30, n_devices=1)
 
 
+class _FakeDevice:
+    device_kind = "fake"
+
+    def __init__(self, platform, stats):
+        self.platform, self._stats = platform, stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("platform,stats,want", [
+    ("cpu", None, None),
+    ("tpu", {"bytes_limit": 16_909_336_064}, 16_909_336_064),
+    ("tpu", None, RuntimeError),
+    ("tpu", {"bytes_in_use": 0}, RuntimeError),
+])
+def test_device_memory_bytes(platform, stats, want):
+    """The planner reads the device's own HBM limit; a TPU that reports
+    none is an error, never a guessed size."""
+    from repro.core.batching import device_memory_bytes
+    dev = _FakeDevice(platform, stats)
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError, match="bytes_limit"):
+            device_memory_bytes(dev)
+    else:
+        assert device_memory_bytes(dev) == want
+
+
+def test_planned_chunks_are_equal_and_exact():
+    """A batch over the memory plan splits into equal chunks (one compiled
+    program) whose results equal the unchunked solve bitwise."""
+    from repro.core.batching import BUDGET_FRACTION, PROGRAM_BYTES_FACTOR
+    batch = random_lp_batch(RNG, B=50, m=10, n=6)
+    per_lp = batch.bytes_per_lp() * PROGRAM_BYTES_FACTOR["tableau"]
+    device_bytes = int(20 * per_lp / BUDGET_FRACTION) + 1
+    assert max_chunk_size(batch, device_bytes) == 20
+    sizes = []
+
+    def solver(sub, **kw):
+        sizes.append(sub.batch)
+        return solve_batched_jax(sub, **kw)
+
+    res = solve_batched(batch, solver=solver, device_bytes=device_bytes)
+    assert sizes == [17, 17, 16]
+    full = solve_batched_jax(batch)
+    for f in ("status", "iterations", "objective", "x"):
+        np.testing.assert_array_equal(getattr(res, f), getattr(full, f))
+
+
+def test_rank1_update_snaps_cancellation_residue():
+    """Entries that cancel to within CANCEL_ULPS rounding units of their
+    operands become exactly 0 (what a correctly rounded divide leaves on an
+    equality pair); a larger difference and a clean update are kept."""
+    import jax.numpy as jnp
+    from repro.core.lp import CANCEL_ULPS
+    from repro.core.simplex import rank1_update
+    eps = np.finfo(np.float32).eps
+    T = np.array([[[3.0, 1.0, 2.0]]], np.float32)
+    factor = np.ones((1, 1), np.float32)
+    pivrow = np.array([[3.0 * (1 + eps), 0.5,
+                        2.0 * (1 + (CANCEL_ULPS + 2) * eps)]], np.float32)
+    out = np.asarray(rank1_update(jnp.asarray(T), jnp.asarray(factor),
+                                  jnp.asarray(pivrow)))[0, 0]
+    assert out[0] == 0.0
+    assert out[1] == 0.5
+    assert out[2] == np.float32(2.0) - pivrow[0, 2] != 0.0
+
+
 def test_solution_feasibility():
     batch = random_lp_batch(RNG, B=32, m=12, n=8, feasible_start=False)
     res = solve_batched_jax(batch)
