@@ -3,7 +3,7 @@ batches solved with the telemetry plane on, reported as a per-wave
 p50/p99 latency + solves/sec table derived from each wave's SolveReport.
 
     PYTHONPATH=src python examples/serve_batched.py [--fixture afiro]
-        [--waves 4] [--batch 16] [--backend tableau] [--trace out.json]
+        [--waves 4] [--batch 16] [--backend tableau] [--trace DIR]
 
 This is the first concrete step on the ROADMAP item "Streaming solve
 service: continuous batching over shape classes".  What exists here: a
@@ -16,19 +16,25 @@ admission loop (``FrontierScheduler``'s source/sink protocol is the
 intended API), heterogeneous shape-class bucketing, and a Poisson load
 generator.
 
-``--trace`` additionally writes a Chrome/Perfetto trace-event JSON of the
-last wave's span tree (canonicalize -> dispatch -> segment k -> bucket
-gathers) — load it at https://ui.perfetto.dev.
+``--trace DIR`` additionally re-solves the last wave through the
+compaction scheduler under ``jax.profiler`` and writes the trace under
+``DIR``, with a ``perfetto_trace.json.gz`` beside it: the library's
+``lp.*`` spans (``lp.canonicalize`` -> ``lp.dispatch`` -> ``lp.segment[..]``
+-> ``lp.bucket_gather`` -> ``lp.recover``) and the device's ops on one
+timeline — load it at https://ui.perfetto.dev.
 """
 from __future__ import annotations
 
 import argparse
 
+import glob
+import os
+
+import jax
 import numpy as np
 
 from repro.core import OPTIMAL, solve_batched, solve_batched_compacted
 from repro.io.mps import fixture_path, perturbed_sequence, read_mps
-from repro.obs import SpanTracer
 
 
 def serve(fixture: str = "afiro", waves: int = 4, batch: int = 16,
@@ -45,7 +51,6 @@ def serve(fixture: str = "afiro", waves: int = 4, batch: int = 16,
     print("-" * len(header))
     rows = []
     warm = None
-    tracer = None
     for k, gb in enumerate(stream):
         # monolithic chunked driver: captures terminal state, so each wave
         # warm-starts from the previous one (the repeated-solve win)
@@ -82,14 +87,16 @@ def serve(fixture: str = "afiro", waves: int = 4, batch: int = 16,
         print(f"\n{total_lps} LPs in {total_wall:.3f}s "
               f"({total_lps / total_wall:.1f} solves/s sustained)")
     if trace is not None:
-        # one compacted multi-segment re-solve of the final wave with the
-        # span tracer on — the documented way to get a Perfetto trace
-        tracer = SpanTracer()
-        solve_batched_compacted(stream[-1], backend=backend, telemetry=True,
-                                tracer=tracer)
-        tracer.to_perfetto(trace)
-        print(f"wrote Perfetto trace of a compacted {fixture!r} solve to "
-              f"{trace} (open at https://ui.perfetto.dev)")
+        # one compacted multi-segment re-solve of the final wave under the
+        # profiler — the documented way to get a Perfetto trace
+        with jax.profiler.trace(trace, create_perfetto_trace=True):
+            solve_batched_compacted(stream[-1], backend=backend,
+                                    telemetry=True)
+        found = glob.glob(os.path.join(trace, "**", "perfetto_trace.json.gz"),
+                          recursive=True)
+        print(f"wrote a profiler trace of a compacted {fixture!r} solve to "
+              f"{found[-1] if found else trace} (open at "
+              "https://ui.perfetto.dev)")
     return rows
 
 
@@ -100,8 +107,8 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--backend", default="tableau",
                     choices=("tableau", "revised", "pdhg"))
-    ap.add_argument("--trace", default=None,
-                    help="write a Perfetto trace JSON of the last wave")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="write a profiler trace of the last wave under DIR")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     serve(fixture=args.fixture, waves=args.waves, batch=args.batch,

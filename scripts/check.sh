@@ -124,9 +124,11 @@ telemetry_smoke() {
 # disabled by default (stats None, answers identical to the telemetry run),
 # counters summing exactly to LPResult.iterations, phase lanes matching the
 # float64 oracle on the exact engines, and a compacted+traced solve whose
-# span tree exports as valid Perfetto trace-event JSON
-import json, os, tempfile
+# lp.* spans reach both the SpanTracer and a jax.profiler trace
+import glob, os, tempfile
+import jax
 import numpy as np
+from jax.profiler import ProfileData
 from repro.core import solve_batched, solve_batched_compacted
 from repro.core.reference import solve_batched_reference_detailed
 from repro.io.mps import fixture_path, perturbed_batch, read_mps
@@ -161,17 +163,21 @@ else:
     tag = "kkt lanes finite"
 
 tracer = SpanTracer()
-solve_batched_compacted(gb, backend=backend, telemetry=True, tracer=tracer)
-assert tracer.roots, "compacted solve recorded no spans"
-with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as f:
-    path = f.name
-tracer.to_perfetto(path)
-events = json.load(open(path))["traceEvents"]
-os.unlink(path)
-assert any(e.get("name", "").startswith("segment") for e in events), \
-    "Perfetto export lost the segment spans"
+with tempfile.TemporaryDirectory() as log_dir:
+    with jax.profiler.trace(log_dir):
+        solve_batched_compacted(gb, backend=backend, telemetry=True,
+                                tracer=tracer)
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    events = [ev.name for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:") for line in plane.lines
+              for ev in line.events if ev.name.startswith("lp.")]
+recorded = [s.name for r in tracer.roots for s in r.walk()]
+assert any(n.startswith("lp.segment") for n in recorded), \
+    "compacted solve recorded no segment spans"
+assert sorted(recorded) == sorted(events), \
+    "the profiler trace and the SpanTracer hold different spans"
 print(f"  {backend}: {int(rep.iterations.sum())} iterations counted, "
-      f"{tag}, {len(events)} trace events")
+      f"{tag}, {len(events)} spans")
 print("telemetry smoke OK")
 EOF
 }

@@ -7,15 +7,21 @@ Eq. 5) and overlaps H2D/D2H copies with kernel execution via CUDA streams
 * the memory budget is the HBM limit each device reports
   (``memory_stats()["bytes_limit"]``) x device count, spent per LP by the
   *compiled* program's footprint, not by one tableau,
-* chunk *k+1* is `jax.device_put` (H2D DMA) while chunk *k*'s solve is still
-  in flight — JAX's async dispatch gives the CUDA-streams pipeline for free:
-  we enqueue transfer->solve per chunk and only block when gathering results
-  (the paper's "all H2D, all kernels, all D2H per stream" schedule),
-* results are fetched with one blocking gather at the end (D2H-res).
+* chunks run one after another, with no overlap: the default engine
+  (``solve_batched_jax``) returns host arrays, so chunk *k* has been put,
+  solved and fetched before chunk *k+1*'s transfer starts.  The paper's
+  overlap of one chunk's H2D with another's solve is not built (ROADMAP
+  A9); the ``chunk`` arg of the ``lp.*`` spans shows the order in a
+  profiler trace,
+* the chunks' host results are concatenated at the end.
+
+One call is an ``lp.solve`` span (``obs.trace``) holding the
+canonicalisation, the plan and each chunk's spans.
 """
 from __future__ import annotations
 
 import inspect
+import itertools
 import math
 from typing import Callable, Optional
 
@@ -24,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.report import SolveReport
+from ..obs.trace import span, tagged
 from .compaction import solve_batched_compacted
 from .forms import ensure_canonical, finish_result, prepare_warm
 from .lp import (LPBatch, LPResult, WarmStart, canonicalize_backend,
@@ -40,6 +47,8 @@ BUDGET_FRACTION = 0.6
 # carries two tableaux, phase compaction a third, and the ratio test
 # (B, m, C) temporaries; tests/test_tpu_compile.py checks a planned chunk.
 PROGRAM_BYTES_FACTOR = {"tableau": 8, "revised": 10, "pdhg": 2}
+# the solve_id of each call's lp.solve span
+_SOLVE_IDS = itertools.count()
 
 
 def device_memory_bytes(device=None) -> Optional[int]:
@@ -157,122 +166,129 @@ def solve_batched(batch: LPBatch, *, solver: Optional[Callable] = None,
     compile one XLA program per pow2 bucket instead of one per batch size,
     at the cost of solving up to 2x LPs per dispatch (replicas terminate
     in lockstep with their originals, so wall-clock cost is near zero)."""
-    canonicalize_backend(backend)
-    batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale)
-    warm = prepare_warm(warm, rec, batch)
-    if solver is None:
-        if backend != "tableau":
-            # registry dispatch (core/lp.py BACKEND_REGISTRY): each engine
-            # owns its monolithic and compaction-scheduled entry points
-            solver = resolve_backend(backend, compacted=compaction)
-        else:
-            solver = (solve_batched_compacted if compaction
-                      else solve_batched_jax)
-        solver_kwargs["pricing"] = pricing
-    elif compaction or pricing != "dantzig" or backend != "tableau" \
-            or warm is not None:
-        # only introspect when a kwarg actually needs forwarding, so
-        # non-introspectable callables keep working on the default path
-        params = inspect.signature(solver).parameters
-        has_varkw = any(p.kind is inspect.Parameter.VAR_KEYWORD
-                        for p in params.values())
-        if compaction:
-            if "compaction" not in params and not has_varkw:
-                raise ValueError(
-                    f"compaction=True but solver {getattr(solver, '__name__', solver)!r} "
-                    "does not accept a 'compaction' kwarg; use solver=None "
-                    "(solve_batched_compacted) or a compaction-aware solver such "
-                    "as kernels.ops.solve_batched_pallas")
-            solver_kwargs["compaction"] = True
-        if pricing != "dantzig":
-            if "pricing" in params or has_varkw:
-                solver_kwargs.setdefault("pricing", pricing)
+    with span("lp.solve", solve_id=next(_SOLVE_IDS), B=batch.batch,
+              m=batch.m, n=batch.n):
+        canonicalize_backend(backend)
+        batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale)
+        warm = prepare_warm(warm, rec, batch)
+        if solver is None:
+            if backend != "tableau":
+                # registry dispatch (core/lp.py BACKEND_REGISTRY): each engine
+                # owns its monolithic and compaction-scheduled entry points
+                solver = resolve_backend(backend, compacted=compaction)
             else:
+                solver = (solve_batched_compacted if compaction
+                          else solve_batched_jax)
+            solver_kwargs["pricing"] = pricing
+        elif compaction or pricing != "dantzig" or backend != "tableau" \
+                or warm is not None:
+            # only introspect when a kwarg actually needs forwarding, so
+            # non-introspectable callables keep working on the default path
+            params = inspect.signature(solver).parameters
+            has_varkw = any(p.kind is inspect.Parameter.VAR_KEYWORD
+                            for p in params.values())
+            if compaction:
+                if "compaction" not in params and not has_varkw:
+                    raise ValueError(
+                        f"compaction=True but solver {getattr(solver, '__name__', solver)!r} "
+                        "does not accept a 'compaction' kwarg; use solver=None "
+                        "(solve_batched_compacted) or a compaction-aware solver such "
+                        "as kernels.ops.solve_batched_pallas")
+                solver_kwargs["compaction"] = True
+            if pricing != "dantzig":
+                if "pricing" in params or has_varkw:
+                    solver_kwargs.setdefault("pricing", pricing)
+                else:
+                    raise ValueError(
+                        f"pricing={pricing!r} requested but solver "
+                        f"{getattr(solver, '__name__', solver)!r} does not accept "
+                        "a 'pricing' kwarg; use solver=None or a pricing-aware "
+                        "solver")
+            if backend != "tableau":
+                if "backend" in params or has_varkw:
+                    solver_kwargs.setdefault("backend", backend)
+                else:
+                    raise ValueError(
+                        f"backend={backend!r} requested but solver "
+                        f"{getattr(solver, '__name__', solver)!r} does not accept "
+                        "a 'backend' kwarg; use solver=None or a backend-aware "
+                        "solver such as kernels.ops.solve_batched_pallas")
+            if warm is not None and "warm" not in params and not has_varkw:
                 raise ValueError(
-                    f"pricing={pricing!r} requested but solver "
+                    f"warm= requested but solver "
                     f"{getattr(solver, '__name__', solver)!r} does not accept "
-                    "a 'pricing' kwarg; use solver=None or a pricing-aware "
+                    "a 'warm' kwarg; use solver=None or a warm-start-aware "
                     "solver")
-        if backend != "tableau":
-            if "backend" in params or has_varkw:
-                solver_kwargs.setdefault("backend", backend)
-            else:
-                raise ValueError(
-                    f"backend={backend!r} requested but solver "
-                    f"{getattr(solver, '__name__', solver)!r} does not accept "
-                    "a 'backend' kwarg; use solver=None or a backend-aware "
-                    "solver such as kernels.ops.solve_batched_pallas")
-        if warm is not None and "warm" not in params and not has_varkw:
-            raise ValueError(
-                f"warm= requested but solver "
-                f"{getattr(solver, '__name__', solver)!r} does not accept "
-                "a 'warm' kwarg; use solver=None or a warm-start-aware "
-                "solver")
-    B = batch.batch
-    perm = None
-    if sort_by_difficulty and B > 1:
-        perm = np.argsort(difficulty_proxy(batch), kind="stable")
-        batch = LPBatch(A=np.asarray(batch.A)[perm],
-                        b=np.asarray(batch.b)[perm],
-                        c=np.asarray(batch.c)[perm],
-                        ub=None if batch.ub is None
-                        else np.asarray(batch.ub)[perm])
-        if warm is not None:
-            warm = warm.take(perm)
-    unpad_B = None
-    if pad_to_bucket and B > 1:
-        Bp = 1 << (B - 1).bit_length()
-        if Bp != B:
-            idx = np.arange(Bp) % B
-            batch = LPBatch(A=np.asarray(batch.A)[idx],
-                            b=np.asarray(batch.b)[idx],
-                            c=np.asarray(batch.c)[idx],
+        B = batch.batch
+        perm = None
+        if sort_by_difficulty and B > 1:
+            perm = np.argsort(difficulty_proxy(batch), kind="stable")
+            batch = LPBatch(A=np.asarray(batch.A)[perm],
+                            b=np.asarray(batch.b)[perm],
+                            c=np.asarray(batch.c)[perm],
                             ub=None if batch.ub is None
-                            else np.asarray(batch.ub)[idx])
+                            else np.asarray(batch.ub)[perm])
             if warm is not None:
-                warm = warm.take(idx)
-            unpad_B, B = B, Bp
+                warm = warm.take(perm)
+        unpad_B = None
+        if pad_to_bucket and B > 1:
+            Bp = 1 << (B - 1).bit_length()
+            if Bp != B:
+                idx = np.arange(Bp) % B
+                batch = LPBatch(A=np.asarray(batch.A)[idx],
+                                b=np.asarray(batch.b)[idx],
+                                c=np.asarray(batch.c)[idx],
+                                ub=None if batch.ub is None
+                                else np.asarray(batch.ub)[idx])
+                if warm is not None:
+                    warm = warm.take(idx)
+                unpad_B, B = B, Bp
 
-    def call(sub, sub_warm):
-        # warm is passed per-call (never via solver_kwargs) because each
-        # chunk gets its own slice of the carrier
-        if sub_warm is not None:
-            return solver(sub, warm=sub_warm, **solver_kwargs)
-        return solver(sub, **solver_kwargs)
+        def call(i, sub, sub_warm):
+            # warm is passed per-call (never via solver_kwargs) because each
+            # chunk gets its own slice of the carrier
+            with tagged(chunk=i):
+                if sub_warm is not None:
+                    return solver(sub, warm=sub_warm, **solver_kwargs)
+                return solver(sub, **solver_kwargs)
 
-    if chunk_size is None:
-        if device_bytes is None:
-            device_bytes = device_memory_bytes()
-        chunk_size = B if device_bytes is None else max_chunk_size(
-            batch, device_bytes, n_devices, backend=backend)
-        if chunk_size < B:
-            chunk_size = -(-B // -(-B // chunk_size))
-    if chunk_size >= B:
-        res = call(batch, warm)
+        with span("lp.plan") as plan:
+            if chunk_size is None:
+                if device_bytes is None:
+                    device_bytes = device_memory_bytes()
+                chunk_size = B if device_bytes is None else max_chunk_size(
+                    batch, device_bytes, n_devices, backend=backend)
+                if chunk_size < B:
+                    chunk_size = -(-B // -(-B // chunk_size))
+            n_chunks = 1 if chunk_size >= B else math.ceil(B / chunk_size)
+            plan.set(chunk_size=min(chunk_size, B), n_chunks=n_chunks)
+        if n_chunks == 1:
+            res = call(0, batch, warm)
+            return finish_result(rec, _unpermute(_unpad(res, unpad_B), perm))
+
+        pending = []
+        for i in range(n_chunks):
+            s, e = i * chunk_size, min((i + 1) * chunk_size, B)
+            sub = LPBatch(A=batch.A[s:e], b=batch.b[s:e], c=batch.c[s:e],
+                          ub=None if batch.ub is None else batch.ub[s:e])
+            # the default engine returns host arrays, so this blocks until the
+            # chunk is solved and fetched: the next chunk's transfer starts
+            # after it (no overlap, ROADMAP A9)
+            pending.append(call(i, sub, None if warm is None
+                                else warm.slice(s, e)))
+
+        def cat(field):
+            vals = [getattr(r, field) for r in pending]
+            if any(v is None for v in vals):  # a chunk without a certificate
+                return None
+            return np.concatenate([np.asarray(v) for v in vals])
+
+        res = LPResult(x=cat("x"), objective=cat("objective"),
+                       status=cat("status"), iterations=cat("iterations"),
+                       y=cat("y"), z=cat("z"),
+                       warm=WarmStart.concat([r.warm for r in pending]),
+                       stats=SolveReport.concat([r.stats for r in pending]))
         return finish_result(rec, _unpermute(_unpad(res, unpad_B), perm))
-
-    n_chunks = math.ceil(B / chunk_size)
-    pending = []
-    for i in range(n_chunks):
-        s, e = i * chunk_size, min((i + 1) * chunk_size, B)
-        sub = LPBatch(A=batch.A[s:e], b=batch.b[s:e], c=batch.c[s:e],
-                      ub=None if batch.ub is None else batch.ub[s:e])
-        # async dispatch: this returns before the device finishes; the next
-        # chunk's H2D overlaps this chunk's compute (CUDA-streams analogue)
-        pending.append(call(sub, None if warm is None else warm.slice(s, e)))
-
-    def cat(field):
-        vals = [getattr(r, field) for r in pending]
-        if any(v is None for v in vals):  # a chunk without a certificate
-            return None
-        return np.concatenate([np.asarray(v) for v in vals])
-
-    res = LPResult(x=cat("x"), objective=cat("objective"),
-                   status=cat("status"), iterations=cat("iterations"),
-                   y=cat("y"), z=cat("z"),
-                   warm=WarmStart.concat([r.warm for r in pending]),
-                   stats=SolveReport.concat([r.stats for r in pending]))
-    return finish_result(rec, _unpermute(_unpad(res, unpad_B), perm))
 
 
 def _unpad(res: LPResult, B) -> LPResult:

@@ -56,7 +56,8 @@ from typing import List, Optional
 import numpy as np
 
 from .batching import solve_batched
-from .compaction import FrontierScheduler, _maybe_span
+from ..obs.trace import span
+from .compaction import FrontierScheduler
 from .forms import (GeneralLPBatch, Recovery, canonicalize, general_violation,
                     rebind_bounds)
 from .lp import (INFEASIBLE, ITERATION_LIMIT, OPTIMAL, UNBOUNDED, LPBatch,
@@ -372,8 +373,8 @@ def branch_and_bound(g: GeneralLPBatch, *, integer=None,
                 ws = WarmStart.concat(
                     [nd.warm if nd.warm is not None
                      else _cold_carrier(lp0.m, lp0.n) for nd in take])
-            with _maybe_span(tracer, "bnb_dispatch", nodes=len(take),
-                             open_nodes=len(open_nodes)):
+            with span("lp.bnb_dispatch", tracer, nodes=len(take),
+                      open_nodes=len(open_nodes)):
                 res_can = solve_batched(lp_f, backend=backend,
                                         pricing=pricing, warm=ws,
                                         pad_to_bucket=True, **solver_kwargs)

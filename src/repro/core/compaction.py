@@ -34,7 +34,6 @@ phase-compacted tableau (stage "p2") — see core/simplex.py for Level 1.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import time
@@ -46,6 +45,7 @@ import numpy as np
 
 from ..obs.report import report_from_counters
 from ..obs.telemetry import init_telemetry, tel_to_numpy, zeros_numpy
+from ..obs.trace import span
 from .forms import ensure_canonical, finish_result, prepare_warm
 from .lp import (ITERATION_LIMIT, OPTIMAL, LPBatch, LPResult, WarmStart,
                  canonicalize_backend, default_max_iters, resolve_backend)
@@ -417,14 +417,6 @@ class JaxBackend:
 # The scheduler
 # ---------------------------------------------------------------------------
 
-def _maybe_span(tracer, name, **args):
-    """``tracer.span`` when a tracer is attached, a no-op context otherwise
-    (run_schedule and the frontier scheduler trace opportunistically)."""
-    if tracer is None:
-        return contextlib.nullcontext()
-    return tracer.span(name, **args)
-
-
 def run_schedule(backend, state: CompactionState, orig: np.ndarray, B: int,
                  n: int, *, max_iters: int, config: CompactionConfig,
                  stats_out: Optional[List[SegmentStat]] = None,
@@ -490,7 +482,7 @@ def run_schedule(backend, state: CompactionState, orig: np.ndarray, B: int,
         if bucket >= cur or n_run >= config.compact_threshold * cur:
             return state, orig, status
         # retire everyone's current results, then gather the survivors
-        with _maybe_span(tracer, "bucket_gather", stage=stage,
+        with span("lp.bucket_gather", tracer, stage=stage,
                          src_bucket=cur, dst_bucket=bucket,
                          survivors=n_run):
             flush(state, orig, stage)
@@ -516,18 +508,16 @@ def run_schedule(backend, state: CompactionState, orig: np.ndarray, B: int,
                 break
             steps = min(config.segment_k, budget)
             bucket = len(orig)
-            with _maybe_span(tracer, f"segment[{stage}]", k=seg,
-                             bucket=bucket, max_steps=steps) as sp:
+            with span(f"lp.segment[{stage}]", tracer, k=seg,
+                      bucket=bucket, max_steps=steps) as sp:
                 state, done = runner(state, steps)
                 budget -= max(1, done)
                 # a triggered bucket gather nests under its segment span
                 state, orig, status = maybe_compact(state, orig, stage)
                 survivors = int((status == _RUNNING).sum())
-                if sp is not None:
-                    # lane occupancy after the (possibly compacted) segment
-                    sp.args["steps"] = int(done)
-                    sp.args["survivors"] = survivors
-                    sp.args["occupancy"] = survivors / max(1, len(orig))
+                # lane occupancy after the (possibly compacted) segment
+                sp.set(steps=int(done), survivors=survivors,
+                       occupancy=survivors / max(1, len(orig)))
             if stats_out is not None:
                 # survivor count is compaction-invariant (gathers only drop
                 # terminal LPs), so the post-compact host status serves both
@@ -611,8 +601,8 @@ def solve_batched_compacted(batch: LPBatch, *, dtype=jnp.float32,
             compact_threshold=compact_threshold, pricing=pricing,
             stats_out=stats_out, presolve=presolve, scale=scale, warm=warm,
             telemetry=telemetry, tracer=tracer)
-    with _maybe_span(tracer, "canonicalize"):
-        batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale)
+    batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale,
+                                  tracer=tracer)
     m, n = batch.m, batch.n
     if max_iters is None:
         max_iters = default_max_iters(m, n)
@@ -623,8 +613,8 @@ def solve_batched_compacted(batch: LPBatch, *, dtype=jnp.float32,
     if feas_tol is None:
         feas_tol = 1e-5 if dtype == jnp.float32 else 1e-7
     backend = JaxBackend(m, n, tol, feas_tol, dtype, pricing=pricing)
-    with _maybe_span(tracer, "dispatch", backend="tableau", B=batch.batch,
-                     m=m, n=n):
+    with span("lp.dispatch", tracer, backend="tableau", B=batch.batch, m=m,
+              n=n):
         state = backend.init(jnp.asarray(batch.A, dtype),
                              jnp.asarray(batch.b, dtype),
                              jnp.asarray(batch.c, dtype),
@@ -640,8 +630,7 @@ def solve_batched_compacted(batch: LPBatch, *, dtype=jnp.float32,
         pad_multiple=backend.pad_multiple)
     res = run_schedule(backend, state, orig, B, n, max_iters=int(max_iters),
                        config=cfg, stats_out=stats_out, tracer=tracer)
-    with _maybe_span(tracer, "recover"):
-        return finish_result(rec, res)
+    return finish_result(rec, res, tracer=tracer)
 
 
 # ---------------------------------------------------------------------------
@@ -760,12 +749,10 @@ class FrontierScheduler:
             active = tags >= 0
             if not active.any():
                 return retired
-            with _maybe_span(self.tracer, "segment[frontier]",
-                             lanes=self.lanes,
-                             occupied=int(active.sum())) as sp:
+            with span("lp.segment[frontier]", self.tracer,
+                      lanes=self.lanes, occupied=int(active.sum())) as sp:
                 state, done = be.run_combined(state, self.segment_k)
-                if sp is not None:
-                    sp.args["steps"] = int(done)
+                sp.set(steps=int(done))
             status = be.status_host(state)
             # per-LP budget: over-budget lanes retire as ITERATION_LIMIT
             over = (active & (status == _RUNNING)

@@ -41,7 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.report import report_from_counters
 from ..obs.telemetry import tel_to_numpy
-from .compaction import _maybe_span
+from ..obs.trace import span
 from .forms import ensure_canonical, finish_result
 from .lp import (LPBatch, LPResult, OPTIMAL, ITERATION_LIMIT,
                  canonicalize_backend, default_max_iters)
@@ -345,7 +345,8 @@ def solve_shard_map(batch: LPBatch, mesh: Mesh, *, dtype=jnp.float32,
     GeneralLPBatch inputs canonicalize on the host before sharding and
     recover after the gather, in both the one-shot and segmented modes."""
     canonicalize_backend(backend)
-    batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale)
+    batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale,
+                                  tracer=tracer)
     m, n = batch.m, batch.n
     max_iters, tol = _backend_defaults(backend, max_iters, tol, m, n, dtype)
 
@@ -393,7 +394,8 @@ def solve_shard_map(batch: LPBatch, mesh: Mesh, *, dtype=jnp.float32,
         return finish_result(rec, run_schedule(runner, state, orig, orig_B, n,
                                                max_iters=budget, config=cfg,
                                                stats_out=stats_out,
-                                               tracer=tracer))
+                                               tracer=tracer),
+                             tracer=tracer)
 
     A, b, c, ub, axes, orig, _ = shard_batch(batch, mesh, dtype)
     spec = P(axes)
@@ -415,8 +417,8 @@ def solve_shard_map(batch: LPBatch, mesh: Mesh, *, dtype=jnp.float32,
                         jax.ShapeDtypeStruct(c.shape, c.dtype),
                         jax.ShapeDtypeStruct(ub.shape, ub.dtype))
     t0 = time.perf_counter()
-    with _maybe_span(tracer, "dispatch", backend=backend, B=batch.batch,
-                     m=m, n=n):
+    with span("lp.dispatch", tracer, backend=backend, B=batch.batch, m=m,
+              n=n):
         out = fn(A, b, c, ub)
         x, obj, status, iters, y, z = out[:6]
         stats = None
@@ -432,4 +434,4 @@ def solve_shard_map(batch: LPBatch, mesh: Mesh, *, dtype=jnp.float32,
                    iterations=np.asarray(iters)[:orig],
                    y=np.asarray(y)[:orig], z=np.asarray(z)[:orig],
                    stats=stats)
-    return finish_result(rec, res)
+    return finish_result(rec, res, tracer=tracer)

@@ -53,6 +53,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from ..obs.trace import span
 from .lp import INFEASIBLE, OPTIMAL, LPBatch, LPResult, WarmStart
 
 # Row senses (MPS letters).
@@ -483,156 +484,159 @@ def canonicalize(g: GeneralLPBatch, *, presolve: bool = True,
     """
     if scale is None:
         scale = presolve
-    B, m, n = g.batch, g.m, g.n
-    lo, hi = g.row_bounds()
-    A = np.asarray(g.A, np.float64)
-    csign = 1.0 if g.maximize else -1.0
-    cmax = csign * np.asarray(g.c, np.float64)   # standard form maximizes
-    lb = np.asarray(g.lb, np.float64)
-    ub = np.asarray(g.ub, np.float64)
+    with span("lp.canonicalize.presolve"):
+        B, m, n = g.batch, g.m, g.n
+        lo, hi = g.row_bounds()
+        A = np.asarray(g.A, np.float64)
+        csign = 1.0 if g.maximize else -1.0
+        cmax = csign * np.asarray(g.c, np.float64)   # standard form maximizes
+        lb = np.asarray(g.lb, np.float64)
+        ub = np.asarray(g.ub, np.float64)
 
-    baseline = np.zeros((B, n))
-    keep_col = np.ones(n, bool)
-    keep_row = np.ones(m, bool)
-    status_override = np.full(B, -1, np.int16)
-    fixed = np.zeros(n, bool)
-    droppable = np.zeros(n, bool)
+        baseline = np.zeros((B, n))
+        keep_col = np.ones(n, bool)
+        keep_row = np.ones(m, bool)
+        status_override = np.full(B, -1, np.int16)
+        fixed = np.zeros(n, bool)
+        droppable = np.zeros(n, bool)
 
-    if presolve:
-        # --- fixed variables: lb == ub for every batch member ------------
-        fixed = (lb == ub).all(axis=0) & np.isfinite(lb).all(axis=0)
-        # --- empty columns: structurally zero across the batch -----------
-        empty = (A == 0.0).all(axis=(0, 1)) & ~fixed
-        # value each member wants: the cost-optimal bound; keep the column
-        # when any member's *optimizing* bound is infinite — dropping it
-        # would hide unboundedness (the kept zero column's positive-cost
-        # side then has no ratio row, so the solver certifies UNBOUNDED)
-        want_ub = cmax > 0
-        want_lb = cmax < 0
-        val = np.where(want_ub, ub,
-                       np.where(want_lb, lb,
-                                np.where(np.isfinite(lb), lb, ub)))
-        droppable = empty & np.isfinite(val).all(axis=0)
-        sub = fixed | droppable
-        if sub.any():
-            baseline[:, fixed] = lb[:, fixed]
-            baseline[:, droppable] = val[:, droppable]
-            contrib = np.einsum("bmk,bk->bm", A[:, :, sub], baseline[:, sub])
-            lo = lo - contrib
-            hi = hi - contrib
-            keep_col &= ~sub
-        # --- empty rows (after column elimination) ------------------------
-        empty_row = (A[:, :, keep_col] == 0.0).all(axis=(0, 2))
-        if empty_row.any():
-            bad = ((np.where(np.isfinite(lo), lo, -np.inf) > feas_tol)
-                   | (np.where(np.isfinite(hi), hi, np.inf) < -feas_tol))
-            status_override[bad[:, empty_row].any(axis=1)] = INFEASIBLE
-            keep_row &= ~empty_row
+        if presolve:
+            # --- fixed variables: lb == ub for every batch member ------------
+            fixed = (lb == ub).all(axis=0) & np.isfinite(lb).all(axis=0)
+            # --- empty columns: structurally zero across the batch -----------
+            empty = (A == 0.0).all(axis=(0, 1)) & ~fixed
+            # value each member wants: the cost-optimal bound; keep the column
+            # when any member's *optimizing* bound is infinite — dropping it
+            # would hide unboundedness (the kept zero column's positive-cost
+            # side then has no ratio row, so the solver certifies UNBOUNDED)
+            want_ub = cmax > 0
+            want_lb = cmax < 0
+            val = np.where(want_ub, ub,
+                           np.where(want_lb, lb,
+                                    np.where(np.isfinite(lb), lb, ub)))
+            droppable = empty & np.isfinite(val).all(axis=0)
+            sub = fixed | droppable
+            if sub.any():
+                baseline[:, fixed] = lb[:, fixed]
+                baseline[:, droppable] = val[:, droppable]
+                contrib = np.einsum("bmk,bk->bm", A[:, :, sub], baseline[:, sub])
+                lo = lo - contrib
+                hi = hi - contrib
+                keep_col &= ~sub
+            # --- empty rows (after column elimination) ------------------------
+            empty_row = (A[:, :, keep_col] == 0.0).all(axis=(0, 2))
+            if empty_row.any():
+                bad = ((np.where(np.isfinite(lo), lo, -np.inf) > feas_tol)
+                       | (np.where(np.isfinite(hi), hi, np.inf) < -feas_tol))
+                status_override[bad[:, empty_row].any(axis=1)] = INFEASIBLE
+                keep_row &= ~empty_row
 
-    kept = np.flatnonzero(keep_col)
-    rows = np.flatnonzero(keep_row)
-    A = A[:, rows][:, :, kept]
-    lo, hi = lo[:, rows], hi[:, rows]
-    lbk, ubk, ck = lb[:, kept], ub[:, kept], cmax[:, kept]
+        kept = np.flatnonzero(keep_col)
+        rows = np.flatnonzero(keep_row)
+        A = A[:, rows][:, :, kept]
+        lo, hi = lo[:, rows], hi[:, rows]
+        lbk, ubk, ck = lb[:, kept], ub[:, kept], cmax[:, kept]
 
-    # --- bounds: shift finite lower bounds, split free columns -----------
-    lb_fin = np.isfinite(lbk)
-    mixed = lb_fin.any(axis=0) & ~lb_fin.all(axis=0)
-    if mixed.any():
-        raise ValueError(
-            "lower-bound finiteness must be batch-uniform per column "
-            f"(columns {np.flatnonzero(mixed)} mix finite and -inf): the "
-            "canonical batch needs one static shape")
-    free = ~lb_fin[0] if B else ~lb_fin.any(axis=0)
-    shift = np.where(lb_fin, lbk, 0.0)
-    contrib = np.einsum("bmk,bk->bm", A, shift)
-    lo, hi = lo - contrib, hi - contrib
-    ub_shifted = ubk - shift            # finite iff ub finite
-    ub_fin = np.isfinite(ub_shifted)
-    if (ub_fin.any(axis=0) & ~ub_fin.all(axis=0)).any():
-        raise ValueError(
-            "upper-bound finiteness must be batch-uniform per column: the "
-            "canonical batch needs one static shape")
-    bounded_cols = np.flatnonzero(ub_fin.all(axis=0)) if B else np.array([], int)
-    # native bounds by default; row encoding for free (split) columns and,
-    # under bound_rows=True (or per-column via a mask), for the selection
-    if bound_rows is True:
-        ub_cols = bounded_cols
-    elif bound_rows is False:
-        ub_cols = bounded_cols[free[bounded_cols]]
-    else:
-        forced = np.asarray(bound_rows, bool).reshape(n)[kept]
-        ub_cols = bounded_cols[free[bounded_cols] | forced[bounded_cols]]
-    native_cols = np.setdiff1d(bounded_cols, ub_cols)
+    with span("lp.canonicalize.build"):
+        # --- bounds: shift finite lower bounds, split free columns -----------
+        lb_fin = np.isfinite(lbk)
+        mixed = lb_fin.any(axis=0) & ~lb_fin.all(axis=0)
+        if mixed.any():
+            raise ValueError(
+                "lower-bound finiteness must be batch-uniform per column "
+                f"(columns {np.flatnonzero(mixed)} mix finite and -inf): the "
+                "canonical batch needs one static shape")
+        free = ~lb_fin[0] if B else ~lb_fin.any(axis=0)
+        shift = np.where(lb_fin, lbk, 0.0)
+        contrib = np.einsum("bmk,bk->bm", A, shift)
+        lo, hi = lo - contrib, hi - contrib
+        ub_shifted = ubk - shift            # finite iff ub finite
+        ub_fin = np.isfinite(ub_shifted)
+        if (ub_fin.any(axis=0) & ~ub_fin.all(axis=0)).any():
+            raise ValueError(
+                "upper-bound finiteness must be batch-uniform per column: the "
+                "canonical batch needs one static shape")
+        bounded_cols = np.flatnonzero(ub_fin.all(axis=0)) if B else np.array([], int)
+        # native bounds by default; row encoding for free (split) columns and,
+        # under bound_rows=True (or per-column via a mask), for the selection
+        if bound_rows is True:
+            ub_cols = bounded_cols
+        elif bound_rows is False:
+            ub_cols = bounded_cols[free[bounded_cols]]
+        else:
+            forced = np.asarray(bound_rows, bool).reshape(n)[kept]
+            ub_cols = bounded_cols[free[bounded_cols] | forced[bounded_cols]]
+        native_cols = np.setdiff1d(bounded_cols, ub_cols)
 
-    nk = len(kept)
-    nf = int(free.sum())
-    n_can = nk + nf
-    hi_fin = np.isfinite(hi)
-    lo_fin = np.isfinite(lo)
-    # A row bound that is infinite for some members but finite for others
-    # has no faithful static-shape encoding (substituting a large finite
-    # bound would mis-report genuinely unbounded members as OPTIMAL), so
-    # reject it — same contract as the variable-bound uniformity checks.
-    mixed_rows = ((hi_fin.any(axis=0) & ~hi_fin.all(axis=0))
-                  | (lo_fin.any(axis=0) & ~lo_fin.all(axis=0)))
-    if mixed_rows.any():
-        raise ValueError(
-            "row-bound finiteness must be batch-uniform per row (rows "
-            f"{np.flatnonzero(mixed_rows)} mix finite and infinite rhs): "
-            "the canonical batch needs one static shape")
-    hi_rows = np.flatnonzero(hi_fin.all(axis=0))
-    lo_rows = np.flatnonzero(lo_fin.all(axis=0))
-    m_can = len(hi_rows) + len(lo_rows) + len(ub_cols)
+        nk = len(kept)
+        nf = int(free.sum())
+        n_can = nk + nf
+        hi_fin = np.isfinite(hi)
+        lo_fin = np.isfinite(lo)
+        # A row bound that is infinite for some members but finite for others
+        # has no faithful static-shape encoding (substituting a large finite
+        # bound would mis-report genuinely unbounded members as OPTIMAL), so
+        # reject it — same contract as the variable-bound uniformity checks.
+        mixed_rows = ((hi_fin.any(axis=0) & ~hi_fin.all(axis=0))
+                      | (lo_fin.any(axis=0) & ~lo_fin.all(axis=0)))
+        if mixed_rows.any():
+            raise ValueError(
+                "row-bound finiteness must be batch-uniform per row (rows "
+                f"{np.flatnonzero(mixed_rows)} mix finite and infinite rhs): "
+                "the canonical batch needs one static shape")
+        hi_rows = np.flatnonzero(hi_fin.all(axis=0))
+        lo_rows = np.flatnonzero(lo_fin.all(axis=0))
+        m_can = len(hi_rows) + len(lo_rows) + len(ub_cols)
 
-    A_can = np.zeros((B, m_can, n_can))
-    b_can = np.zeros((B, m_can))
-    pos = A if nf == 0 else np.concatenate([A, -A[:, :, free]], axis=2)
-    r0 = len(hi_rows)
-    A_can[:, :r0] = pos[:, hi_rows]
-    b_can[:, :r0] = hi[:, hi_rows]
-    r1 = r0 + len(lo_rows)
-    A_can[:, r0:r1] = -pos[:, lo_rows]
-    b_can[:, r0:r1] = -lo[:, lo_rows]
-    # upper-bound rows: y_j <= ub' (free columns: y+ - y- <= ub')
-    free_slot = np.cumsum(free) - 1      # index into the neg block
-    for k, j in enumerate(ub_cols):
-        i = r1 + k
-        A_can[:, i, j] = 1.0
-        if free[j]:
-            A_can[:, i, nk + free_slot[j]] = -1.0
-        b_can[:, i] = ub_shifted[:, j]
-    c_can = ck if nf == 0 else np.concatenate([ck, -ck[:, free]], axis=1)
-    # native upper bounds: a (B, n_can) vector instead of rows (split
-    # negative parts are unbounded above)
-    ub_can = np.full((B, n_can), np.inf)
-    if len(native_cols):
-        ub_can[:, native_cols] = ub_shifted[:, native_cols]
+        A_can = np.zeros((B, m_can, n_can))
+        b_can = np.zeros((B, m_can))
+        pos = A if nf == 0 else np.concatenate([A, -A[:, :, free]], axis=2)
+        r0 = len(hi_rows)
+        A_can[:, :r0] = pos[:, hi_rows]
+        b_can[:, :r0] = hi[:, hi_rows]
+        r1 = r0 + len(lo_rows)
+        A_can[:, r0:r1] = -pos[:, lo_rows]
+        b_can[:, r0:r1] = -lo[:, lo_rows]
+        # upper-bound rows: y_j <= ub' (free columns: y+ - y- <= ub')
+        free_slot = np.cumsum(free) - 1      # index into the neg block
+        for k, j in enumerate(ub_cols):
+            i = r1 + k
+            A_can[:, i, j] = 1.0
+            if free[j]:
+                A_can[:, i, nk + free_slot[j]] = -1.0
+            b_can[:, i] = ub_shifted[:, j]
+        c_can = ck if nf == 0 else np.concatenate([ck, -ck[:, free]], axis=1)
+        # native upper bounds: a (B, n_can) vector instead of rows (split
+        # negative parts are unbounded above)
+        ub_can = np.full((B, n_can), np.inf)
+        if len(native_cols):
+            ub_can[:, native_cols] = ub_shifted[:, native_cols]
 
-    # Degenerate shells: presolve can empty the canonical problem entirely
-    # (every row redundant and/or every column substituted).  The solvers
-    # need at least one row and one column, so pad with an inert 0.y <= 1
-    # row / zero-cost zero column — neither changes the solution set, and
-    # unboundedness along a padded-away direction is still caught (an empty
-    # entering column has no ratio row).
-    if n_can == 0:
-        n_can = 1
-        A_can = np.zeros((B, m_can, 1))
-        c_can = np.zeros((B, 1))
-        ub_can = np.full((B, 1), np.inf)
-    if m_can == 0:
-        m_can = 1
-        A_can = np.zeros((B, 1, n_can))
-        b_can = np.ones((B, 1))
+        # Degenerate shells: presolve can empty the canonical problem entirely
+        # (every row redundant and/or every column substituted).  The solvers
+        # need at least one row and one column, so pad with an inert 0.y <= 1
+        # row / zero-cost zero column — neither changes the solution set, and
+        # unboundedness along a padded-away direction is still caught (an empty
+        # entering column has no ratio row).
+        if n_can == 0:
+            n_can = 1
+            A_can = np.zeros((B, m_can, 1))
+            c_can = np.zeros((B, 1))
+            ub_can = np.full((B, 1), np.inf)
+        if m_can == 0:
+            m_can = 1
+            A_can = np.zeros((B, 1, n_can))
+            b_can = np.ones((B, 1))
 
     row_scale = col_scale = None
     if scale and m_can and n_can:
-        row_scale, col_scale = _equilibrate(A_can)
-        A_can = A_can * row_scale[:, :, None] * col_scale[:, None, :]
-        b_can = b_can * row_scale
-        c_can = c_can * col_scale
-        # the solver variable is x_s = x / col_scale, so bounds scale too
-        ub_can = ub_can / col_scale
+        with span("lp.canonicalize.scale"):
+            row_scale, col_scale = _equilibrate(A_can)
+            A_can = A_can * row_scale[:, :, None] * col_scale[:, None, :]
+            b_can = b_can * row_scale
+            c_can = c_can * col_scale
+            # the solver variable is x_s = x / col_scale, so bounds scale too
+            ub_can = ub_can / col_scale
 
     lp = LPBatch.from_arrays(A_can, b_can, c_can, ub=ub_can)
     rec = Recovery(general=g, kept=kept, baseline=baseline, shift=shift,
@@ -834,18 +838,27 @@ def canonical_shape(g: GeneralLPBatch, *, presolve: bool = True,
 
 def ensure_canonical(batch, *, presolve: bool = True,
                      scale: Optional[bool] = None,
-                     bound_rows: bool = False):
+                     bound_rows: bool = False, tracer=None):
     """Entry-point shim: pass ``LPBatch`` through untouched; canonicalize a
-    ``GeneralLPBatch``.  Returns (LPBatch, Recovery-or-None)."""
-    if isinstance(batch, GeneralLPBatch):
-        return canonicalize(batch, presolve=presolve, scale=scale,
-                            bound_rows=bound_rows)
-    return batch, None
+    ``GeneralLPBatch`` inside an ``lp.canonicalize`` span.  Returns
+    (LPBatch, Recovery-or-None)."""
+    if not isinstance(batch, GeneralLPBatch):
+        return batch, None
+    with span("lp.canonicalize", tracer, B=batch.batch, m=batch.m,
+              n=batch.n) as sp:
+        lp, rec = canonicalize(batch, presolve=presolve, scale=scale,
+                               bound_rows=bound_rows)
+        sp.set(m_can=lp.m, n_can=lp.n)
+    return lp, rec
 
 
-def finish_result(rec, res: LPResult) -> LPResult:
-    """Entry-point shim: apply ``Recovery`` when the input was general."""
-    return res if rec is None else rec.recover(res)
+def finish_result(rec, res: LPResult, tracer=None) -> LPResult:
+    """Entry-point shim: apply ``Recovery`` when the input was general,
+    inside an ``lp.recover`` span."""
+    if rec is None:
+        return res
+    with span("lp.recover", tracer, B=rec.general.batch):
+        return rec.recover(res)
 
 
 def prepare_warm(warm: Optional[WarmStart], rec: Optional[Recovery],

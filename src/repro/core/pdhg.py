@@ -878,7 +878,8 @@ def solve_batched_pdhg_compacted(
 
     _check_pdhg_pricing(pricing)
     del feas_tol
-    batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale)
+    batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale,
+                                  tracer=tracer)
     m, n = batch.m, batch.n
     if max_iters is None:
         max_iters = default_pdhg_max_iters(m, n)
@@ -908,4 +909,5 @@ def solve_batched_pdhg_compacted(
     return finish_result(rec, run_schedule(backend, state, orig, B, n,
                                            max_iters=rounds, config=cfg,
                                            stats_out=stats_out,
-                                           tracer=tracer))
+                                           tracer=tracer),
+                         tracer=tracer)

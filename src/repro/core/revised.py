@@ -886,7 +886,8 @@ def solve_batched_revised_compacted(
     ``warm`` seeds the initial state (the warm-derived leaves then ride the
     bucket gathers automatically); the compacted result reports
     ``warm=None``."""
-    batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale)
+    batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale,
+                                  tracer=tracer)
     m, n = batch.m, batch.n
     if max_iters is None:
         max_iters = default_max_iters(m, n)
@@ -914,4 +915,5 @@ def solve_batched_revised_compacted(
     return finish_result(rec, run_schedule(backend, state, orig, B, n,
                                            max_iters=int(max_iters),
                                            config=cfg, stats_out=stats_out,
-                                           tracer=tracer))
+                                           tracer=tracer),
+                         tracer=tracer)

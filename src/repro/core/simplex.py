@@ -76,6 +76,7 @@ import numpy as np
 
 from ..obs.report import report_from_counters
 from ..obs.telemetry import init_telemetry, tel_simplex_update, tel_to_numpy
+from ..obs.trace import span
 from .forms import ensure_canonical, finish_result, prepare_warm
 from .lp import (
     BIG,
@@ -810,44 +811,74 @@ def solve_batched_jax(batch: LPBatch, *, dtype=jnp.float32, tol: float | None = 
         tol = 1e-6 if dtype == jnp.float32 else 1e-9
     if feas_tol is None:
         feas_tol = 1e-5 if dtype == jnp.float32 else 1e-7
-    A = jnp.asarray(batch.A, dtype=dtype)
-    b = jnp.asarray(batch.b, dtype=dtype)
-    c = jnp.asarray(batch.c, dtype=dtype)
-    ub = jnp.asarray(batch.upper_bounds(), dtype=dtype)
     rule = canonicalize_rule(pricing)
-    wb = wfl = ww = None
-    if warm is not None and warm.basis is not None:
-        wb = jnp.asarray(warm.basis, jnp.int32)
-        if warm.at_upper is not None:
-            wfl = jnp.asarray(warm.at_upper, bool)
-        # carried weights are only meaningful for devex (its reference
-        # framework is cross-solve state); steepest edge re-initializes
-        # exactly from the warm tableau, dantzig/partial never read them
-        if (rule == "devex" and warm.pricing == rule
-                and warm.weights is not None
-                and np.asarray(warm.weights).shape[1] >= n + m):
-            ww = jnp.asarray(warm.weights, dtype)
+    dt = jax.dtypes.canonicalize_dtype(dtype)
+    with span("lp.h2d") as h2d:
+        with span("lp.h2d.cast"):
+            leaves = {"A": (batch.A, dt), "b": (batch.b, dt),
+                      "c": (batch.c, dt), "ub": (batch.upper_bounds(), dt)}
+            if warm is not None and warm.basis is not None:
+                leaves["wb"] = (warm.basis, np.dtype(np.int32))
+                if warm.at_upper is not None:
+                    leaves["wfl"] = (warm.at_upper, np.dtype(bool))
+                # carried weights are only meaningful for devex (its
+                # reference framework is cross-solve state); steepest edge
+                # re-initializes exactly from the warm tableau,
+                # dantzig/partial never read them
+                if (rule == "devex" and warm.pricing == rule
+                        and warm.weights is not None
+                        and np.asarray(warm.weights).shape[1] >= n + m):
+                    leaves["ww"] = (warm.weights, dt)
+            host = {k: _host_cast(a, t) for k, (a, t) in leaves.items()}
+        with span("lp.h2d.put"):
+            dev = {k: _put(leaves[k][0], h, leaves[k][1])
+                   for k, h in host.items()}
+        h2d.set(bytes_in=sum(getattr(a, "nbytes", 0) for a, _ in
+                             leaves.values()),
+                bytes_out=sum(h.nbytes for h in host.values()))
+    # free the host copies now, as jnp.asarray frees its temporaries
+    del leaves, host
     t0 = time.perf_counter()
-    out = _solve_core_state(
-        A, b, c, ub, wb, wfl, ww,
-        m=m, n=n, max_iters=int(max_iters), tol=float(tol),
-        feas_tol=float(feas_tol), phase_compaction=bool(phase_compaction),
-        pricing=rule, telemetry=bool(telemetry))
-    x, obj, status, iters, y, z, basis, flip, w = out[:9]
-    stats = None
-    if telemetry:
-        jax.block_until_ready(out[9])
-        stats = report_from_counters(tel_to_numpy(out[9]),
-                                     wall_s=time.perf_counter() - t0,
-                                     backend="tableau")
-    capture = WarmStart(m=m, n=n, basis=np.asarray(basis),
-                        at_upper=np.asarray(flip), weights=np.asarray(w),
+    with span("lp.dispatch", B=batch.batch, m=m, n=n):
+        out = _solve_core_state(
+            dev["A"], dev["b"], dev["c"], dev["ub"], dev.get("wb"),
+            dev.get("wfl"), dev.get("ww"),
+            m=m, n=n, max_iters=int(max_iters), tol=float(tol),
+            feas_tol=float(feas_tol), phase_compaction=bool(phase_compaction),
+            pricing=rule, telemetry=bool(telemetry))
+    with span("lp.wait"):
+        jax.block_until_ready(out)
+    wall_s = time.perf_counter() - t0
+    with span("lp.d2h") as d2h:
+        fetched = [np.asarray(a) for a in out[:9]]
+        stats = None
+        if telemetry:
+            counters = tel_to_numpy(out[9])
+            stats = report_from_counters(counters, wall_s=wall_s,
+                                         backend="tableau")
+            fetched += counters.values()
+        d2h.set(arrays=len(fetched), bytes=sum(a.nbytes for a in fetched))
+    x, obj, status, iters, y, z, basis, flip, w = fetched[:9]
+    capture = WarmStart(m=m, n=n, basis=basis, at_upper=flip, weights=w,
                         pricing=rule)
-    res = LPResult(x=np.asarray(x), objective=np.asarray(obj),
-                   status=np.asarray(status), iterations=np.asarray(iters),
-                   y=np.asarray(y), z=np.asarray(z), warm=capture,
-                   stats=stats)
+    res = LPResult(x=x, objective=obj, status=status, iterations=iters,
+                   y=y, z=z, warm=capture, stats=stats)
     return finish_result(rec, res)
+
+
+def _host_cast(a, dtype):
+    """The host half of ``jnp.asarray(a, dtype)``: NumPy's cast of host
+    input (the input itself where it already has ``dtype``); device
+    arrays pass through."""
+    return a if isinstance(a, jax.Array) else np.asarray(a, dtype=dtype)
+
+
+def _put(a, host, dtype):
+    """The rest of ``jnp.asarray(a, dtype)`` once ``host = _host_cast(a,
+    dtype)``: the same calls, so the same transfer and device ops."""
+    if host is a:
+        return jnp.asarray(a, dtype)
+    return jax.lax.convert_element_type(host, dtype)
 
 
 def flops_per_pivot(m: int, n: int, compacted: bool = False) -> int:

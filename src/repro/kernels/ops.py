@@ -58,11 +58,12 @@ from repro.core.forms import ensure_canonical, finish_result, prepare_warm
 from repro.core.lp import (ITERATION_LIMIT, OPTIMAL, LPBatch, LPResult,
                            WarmStart, backend_spec, default_max_iters)
 from repro.core.compaction import (
-    CompactionConfig, CompactionState, JaxBackend, SegmentStat, _maybe_span,
+    CompactionConfig, CompactionState, JaxBackend, SegmentStat,
     _take_jit, auto_segment_k, init_orig, resolve_compact_threshold,
     run_schedule,
 )
 from repro.obs.telemetry import init_telemetry, rows_to_tel, tel_to_rows
+from repro.obs.trace import span
 from repro.core.pdhg import PdhgBackend
 from repro.core.pricing import canonicalize_rule
 from repro.core.revised import RevisedBackend, canonicalize_revised_rule
@@ -341,8 +342,8 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
                          warm: Optional[WarmStart] = None,
                          telemetry: bool = False,
                          tracer=None) -> LPResult:
-    with _maybe_span(tracer, "canonicalize"):
-        batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale)
+    batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale,
+                                  tracer=tracer)
     m, n = batch.m, batch.n
     pricing = canonicalize_rule(pricing)
     warm = prepare_warm(warm, rec, batch)
@@ -371,7 +372,7 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
                 batch, dtype=dtype, tol=tol, max_iters=max_iters,
                 segment_k=segment_k, compact_threshold=compact_threshold,
                 stats_out=stats_out, warm=warm, runner=runner,
-                telemetry=telemetry, tracer=tracer))
+                telemetry=telemetry, tracer=tracer), tracer=tracer)
         from repro.core.pdhg import default_pdhg_max_iters
         from .pdhg_tile import pdhg_pallas
         if warm is not None:
@@ -416,8 +417,8 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
                 m, n, tol, feas_tol, tile_b, dtype=dtype, pricing=rule,
                 refactor_period=refactor_period)
             B = batch.batch
-            with _maybe_span(tracer, "dispatch", backend="revised-pallas",
-                             B=B, m=m, n=n):
+            with span("lp.dispatch", tracer, backend="revised-pallas", B=B,
+                      m=m, n=n):
                 state = runner.init(A, b, c, ub=ub, warm=warm,
                                     telemetry=telemetry)
                 state, orig = init_orig(runner, state, B)
@@ -428,7 +429,8 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
                 pad_multiple=runner.pad_multiple)
             return finish_result(rec, run_schedule(
                 runner, state, orig, B, n, max_iters=int(max_iters),
-                config=cfg, stats_out=stats_out, tracer=tracer))
+                config=cfg, stats_out=stats_out, tracer=tracer),
+                tracer=tracer)
         wb = wu = None
         if warm is not None and warm.basis is not None:
             wb = jnp.asarray(np.asarray(warm.basis), jnp.int32)
@@ -479,8 +481,8 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
         runner = PallasBackend(m, n, tol, feas_tol, tile_b, dtype=dtype,
                                pricing=pricing)
         B = batch.batch
-        with _maybe_span(tracer, "dispatch", backend="tableau-pallas",
-                         B=B, m=m, n=n):
+        with span("lp.dispatch", tracer, backend="tableau-pallas", B=B,
+                  m=m, n=n):
             state = runner.init(A, b, c, ub=ub, telemetry=telemetry)
             state, orig = init_orig(runner, state, B)
         cfg = CompactionConfig(
@@ -492,7 +494,8 @@ def solve_batched_pallas(batch: LPBatch, *, dtype=jnp.float32,
                                                max_iters=int(max_iters),
                                                config=cfg,
                                                stats_out=stats_out,
-                                               tracer=tracer))
+                                               tracer=tracer),
+                             tracer=tracer)
 
     x, obj, status, iters, y, z = simplex_pallas(
         A, b, c, ub, m=m, n=n, tile_b=int(tile_b), max_iters=int(max_iters),

@@ -19,7 +19,7 @@ from typing import Sequence
 import numpy as np
 
 from .telemetry import ALL_LANES, F32_LANES, INT_LANES
-from .trace import Span, spans_to_perfetto
+from .trace import Span
 
 __all__ = ["SolveReport", "Span", "report_from_counters",
            "INT_LANES", "F32_LANES", "ALL_LANES"]
@@ -144,10 +144,6 @@ class SolveReport:
         return "\n".join(lines)
 
     # -- exporters ----------------------------------------------------------
-
-    def to_perfetto(self, path: str | None = None) -> dict:
-        """Chrome/Perfetto trace-event JSON of the span tree."""
-        return spans_to_perfetto(list(self.spans), path=path)
 
     def to_json(self, path: str | None = None) -> str:
         doc = {"summary": self.summary(),

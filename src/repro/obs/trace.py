@@ -1,29 +1,43 @@
-"""Host-side span tracer: nested wall-clock spans + structured event stream.
+"""Host-side spans of a solve, on the profiler's clock, and a structured
+event stream.
 
-The tracer records the host-visible shape of a solve — canonicalize →
-dispatch → segment k → bucket gather → recover — as a tree of ``Span``
-objects with wall-clock bounds and arbitrary key/value args (lane
-occupancy, bucket size, survivor counts).  Instantaneous events (an LP
-retiring, a B&B node fathoming, a frontier admit) land in the same stream.
+``span(name, tracer=None, **args)`` is the library's one span mechanism.
+It always enters ``jax.profiler.TraceAnnotation(name, **args)``: a host
+event that costs under a microsecond when no profiler is tracing, and that
+a ``jax.profiler`` trace records, with its args, on the same clock as the
+device's operations.  When a ``SpanTracer`` is passed as ``tracer=``, or
+installed for a block with ``tracer.active()``, the span is recorded there
+too, as a tree of ``Span`` objects with wall-clock bounds and the same
+args; instantaneous events (an LP retiring, a branch-and-bound node
+fathoming, a frontier admit) land in the same tracer.
 
-Two exporters:
+The library's spans share the prefix ``lp.``.  One ``solve_batched`` call
+is an ``lp.solve`` span holding ``lp.canonicalize`` (general-form input
+only, with ``.presolve``, ``.build`` and ``.scale`` stages), ``lp.plan``,
+then per chunk ``lp.h2d`` (``.cast``, ``.put``), ``lp.dispatch``,
+``lp.wait`` and ``lp.d2h``, and last ``lp.recover`` (general-form input
+only).  ``tagged(chunk=i)`` adds the chunk index to every span of a chunk.
 
-* ``to_jsonl()`` — one JSON object per line, in completion order; the
-  structured event stream that unifies ``SegmentStat`` logs and
-  ``FrontierScheduler`` lifecycle events.
-* ``to_perfetto()`` — Chrome/Perfetto trace-event JSON (``ph: "X"``
-  complete events for spans, ``ph: "i"`` instants), loadable at
-  https://ui.perfetto.dev or chrome://tracing.
-
-Pure host/NumPy-free module: only ``time``/``json``/``dataclasses``.
+For a timeline, trace with ``jax.profiler`` (``create_perfetto_trace=True``
+also writes a file that https://ui.perfetto.dev opens, device ops and
+spans on one timeline); ``SpanTracer.to_jsonl()`` is the structured event
+stream.
 """
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import json
 import time
 from contextlib import contextmanager
-from typing import Any, Callable
+from typing import Any, Callable, Optional
+
+from jax.profiler import TraceAnnotation
+
+_ACTIVE: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_span_tracer", default=None)
+_TAGS: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_span_tags", default=None)
 
 
 @dataclasses.dataclass
@@ -56,6 +70,65 @@ class Span:
             yield from c.walk()
 
 
+class OpenSpan:
+    """The context ``span`` returns: a profiler annotation, and the
+    tracer's ``Span`` (``record``) while one is recording.  The spans
+    opened inside a recorded span are recorded in the same tracer."""
+
+    __slots__ = ("_ann", "_tracer", "_name", "_args", "_token", "record")
+
+    def __init__(self, name: str, tracer, args: dict):
+        self._ann = TraceAnnotation(name, **args)
+        self._tracer = tracer
+        self._name = name
+        self._args = args
+        self._token = None
+        self.record: Optional[Span] = None
+
+    def __enter__(self) -> "OpenSpan":
+        self._ann.__enter__()
+        if self._tracer is not None:
+            self.record = self._tracer._open(self._name, self._args)
+            self._token = _ACTIVE.set(self._tracer)
+        return self
+
+    def set(self, **args: Any) -> None:
+        """Add args known only inside the span (a planned chunk size, the
+        bytes a put made, a segment's survivors)."""
+        self._ann.set_metadata(**args)
+        if self.record is not None:
+            self.record.args.update(args)
+
+    def __exit__(self, *exc) -> bool:
+        if self.record is not None:
+            _ACTIVE.reset(self._token)
+            self._tracer._close(self.record)
+        self._ann.__exit__(*exc)
+        return False
+
+
+def span(name: str, tracer: "SpanTracer | None" = None,
+         **args: Any) -> OpenSpan:
+    """A span named ``name`` with scalar ``args``: always a profiler
+    annotation, and a record in ``tracer`` (default: the tracer installed
+    by ``SpanTracer.active``, if any)."""
+    tags = _TAGS.get()
+    if tags:
+        args = {**tags, **args}
+    return OpenSpan(name, tracer if tracer is not None else _ACTIVE.get(),
+                    args)
+
+
+@contextmanager
+def tagged(**args: Any):
+    """Every span opened inside the block carries ``args`` as well."""
+    token = _TAGS.set({**(_TAGS.get() or {}), **args})
+    try:
+        yield
+    finally:
+        _TAGS.reset(token)
+
+
 class SpanTracer:
     """Records a tree of nested spans plus instantaneous events."""
 
@@ -70,21 +143,33 @@ class SpanTracer:
     def _now(self) -> float:
         return self._clock() - self._origin
 
+    def span(self, name: str, **args: Any) -> OpenSpan:
+        return span(name, tracer=self, **args)
+
     @contextmanager
-    def span(self, name: str, **args: Any):
+    def active(self):
+        """Record every span of the block here, the library's own spans
+        included, without passing ``tracer=`` down."""
+        token = _ACTIVE.set(self)
+        try:
+            yield self
+        finally:
+            _ACTIVE.reset(token)
+
+    def _open(self, name: str, args: dict) -> Span:
         s = Span(name=name, t0=self._now(), depth=len(self._stack),
                  args=dict(args))
         (self._stack[-1].children if self._stack else self.roots).append(s)
         self._stack.append(s)
-        try:
-            yield s
-        finally:
-            s.t1 = self._now()
-            self._stack.pop()
-            d = s.to_dict()
-            d.pop("children")  # the stream is flat; nesting is via depth
-            d.pop("events")
-            self._log.append(d)
+        return s
+
+    def _close(self, s: Span) -> None:
+        s.t1 = self._now()
+        self._stack.pop()
+        d = s.to_dict()
+        d.pop("children")  # the stream is flat; nesting is via depth
+        d.pop("events")
+        self._log.append(d)
 
     def event(self, name: str, **args: Any) -> None:
         """Record an instantaneous event under the current span (or at the
@@ -95,8 +180,6 @@ class SpanTracer:
         target.append({"name": name, "ts": e["ts"], "args": e["args"]})
         self._log.append(e)
 
-    # -- exporters ----------------------------------------------------------
-
     def to_jsonl(self, path: str | None = None) -> str:
         """Structured event stream: one JSON object per line, in completion
         order (events when recorded, spans when closed)."""
@@ -105,50 +188,3 @@ class SpanTracer:
             with open(path, "w") as fh:
                 fh.write(text + ("\n" if text else ""))
         return text
-
-    def to_perfetto(self, path: str | None = None, *, pid: int = 1,
-                    tid: int = 1) -> dict:
-        return spans_to_perfetto(self.roots, path=path, pid=pid, tid=tid,
-                                 extra_events=self.root_events)
-
-
-def spans_to_perfetto(roots, path: str | None = None, *, pid: int = 1,
-                      tid: int = 1, extra_events=()) -> dict:
-    """Chrome trace-event JSON from a span tree (``ph:"X"`` complete events
-    with microsecond timestamps; instants as ``ph:"i"``)."""
-    trace_events = []
-    for e in extra_events:
-        trace_events.append({
-            "name": e["name"], "ph": "i", "cat": "solve", "s": "t",
-            "ts": round(e["ts"] * 1e6, 3), "pid": pid, "tid": tid,
-            "args": _jsonable(e["args"]),
-        })
-    for root in roots:
-        for s in root.walk():
-            trace_events.append({
-                "name": s.name, "ph": "X", "cat": "solve",
-                "ts": round(s.t0 * 1e6, 3), "dur": round(s.dur_s * 1e6, 3),
-                "pid": pid, "tid": tid, "args": _jsonable(s.args),
-            })
-            for e in s.events:
-                trace_events.append({
-                    "name": e["name"], "ph": "i", "cat": "solve", "s": "t",
-                    "ts": round(e["ts"] * 1e6, 3), "pid": pid, "tid": tid,
-                    "args": _jsonable(e["args"]),
-                })
-    doc = {"traceEvents": trace_events, "displayTimeUnit": "ms"}
-    if path is not None:
-        with open(path, "w") as fh:
-            json.dump(doc, fh)
-    return doc
-
-
-def _jsonable(args: dict) -> dict:
-    out = {}
-    for k, v in args.items():
-        if hasattr(v, "item") and getattr(v, "ndim", None) == 0:
-            v = v.item()
-        elif hasattr(v, "tolist"):
-            v = v.tolist()
-        out[k] = v
-    return out

@@ -1,7 +1,7 @@
 """Solver telemetry plane (repro.obs): counter parity against the float64
 oracle, survival through the compaction scheduler and the chunked-sorted
-driver, the telemetry=False zero-overhead guarantee, and the span-tracer
-exporters.
+driver, the telemetry=False zero-overhead guarantee, and the span tracer
+in a profiler trace and its event stream.
 
 The iteration-attribution invariant under test everywhere:
 ``phase1_iters + phase2_iters == LPResult.iterations`` exactly, on every
@@ -279,33 +279,39 @@ def test_stats_none_when_disabled(backend):
 
 
 # ---------------------------------------------------------------------------
-# span tracer + exporters
+# span tracer: the profiler trace and the event stream
 # ---------------------------------------------------------------------------
 
-def test_perfetto_export_valid_and_nested(tmp_path):
+def test_scheduler_spans_in_profiler_trace(profile):
     batch = _mixed_batch(np.random.default_rng(21), B=32)
     tr = SpanTracer()
-    with tr.span("solve", B=batch.batch):
-        res = solve_batched_compacted(batch, segment_k=4, telemetry=True,
-                                      tracer=tr)
-    rep = res.stats
+    solve_batched_compacted(batch, segment_k=4, telemetry=True)  # compile
+    out = {}
+
+    def call():
+        with tr.span("solve", B=batch.batch):
+            out["res"] = solve_batched_compacted(batch, segment_k=4,
+                                                 telemetry=True, tracer=tr)
+    events = [e for e in profile(call)
+              if e[0] == "solve" or e[0].startswith("lp.")]
+    rep = out["res"].stats
     assert rep.spans, "run_schedule must attach the tracer's span tree"
-    path = tmp_path / "trace.json"
-    rep.to_perfetto(str(path))
-    doc = json.loads(path.read_text())  # valid JSON
-    events = doc["traceEvents"]
-    spans = [e for e in events if e["ph"] == "X"]
-    names = {e["name"] for e in spans}
-    assert any(nm.startswith("segment[") for nm in names), names
-    assert "canonicalize" in names and "dispatch" in names
-    # proper nesting: every segment span lies inside the root solve span
-    root = next(e for e in spans if e["name"] == "solve")
-    for e in spans:
-        if e["name"].startswith("segment["):
-            assert e["ts"] >= root["ts"] - 1e-6
-            assert e["ts"] + e["dur"] <= root["ts"] + root["dur"] + 1e-6
-    # flush instants carried through as instant events
-    assert any(e["ph"] == "i" for e in events)
+    names = {e[0] for e in events}
+    assert any(nm.startswith("lp.segment[") for nm in names), names
+    assert "lp.dispatch" in names
+    # proper nesting: every span lies inside the caller's solve span
+    (root,) = [e for e in events if e[0] == "solve"]
+    assert root[3] == {"B": 32}
+    for e in events:
+        assert root[1] <= e[1] and e[2] <= root[2], e
+    segments = [e for e in events if e[0].startswith("lp.segment[")]
+    assert all({"k", "bucket", "max_steps", "steps", "survivors"}
+               <= set(e[3]) for e in segments)
+    # the tracer holds the same spans, and the flush instants
+    recorded = [s.name for r in tr.roots for s in r.walk()]
+    assert sorted(recorded) == sorted(e[0] for e in events)
+    assert any(ev["name"] == "flush" for r in tr.roots for s in r.walk()
+               for ev in s.events)
 
 
 def test_jsonl_stream_unifies_segments_and_events():
@@ -315,7 +321,7 @@ def test_jsonl_stream_unifies_segments_and_events():
     lines = [json.loads(ln) for ln in tr.to_jsonl().splitlines()]
     kinds = {(rec["type"], rec["name"]) for rec in lines}
     assert ("event", "flush") in kinds
-    assert any(t == "span" and nm.startswith("segment[") for t, nm in kinds)
+    assert any(t == "span" and nm.startswith("lp.segment[") for t, nm in kinds)
 
 
 def test_report_algebra_and_summary():
