@@ -28,9 +28,11 @@ core/lp.py).  This module is the bridge:
 4. **scaling** (on by default): geometric-mean row/column equilibration of
    the canonical data, with scales snapped to powers of two so the
    transform is mantissa-exact; unscaling is folded into ``Recovery``.
-   Scaling never changes exact-arithmetic statuses but does change float32
-   pivot paths — it is the f32 accuracy lever for badly-scaled instances
-   (the paper's Sec. 6 concern).
+   It runs over the nonzero pattern the members share, with the scales
+   to the bit of the same passes over the dense array.  Scaling never
+   changes exact-arithmetic statuses but does change float32 pivot paths
+   — it is the f32 accuracy lever for badly-scaled instances (the paper's
+   Sec. 6 concern).
 
 ``Recovery.recover`` maps an ``LPResult`` on the canonical batch back to
 original coordinates: un-scale, un-split, un-shift, re-insert presolved
@@ -319,29 +321,102 @@ def _pow2(s: np.ndarray) -> np.ndarray:
     return np.exp2(np.round(np.log2(s)))
 
 
-def _equilibrate(A: np.ndarray, iters: int = 2):
-    """Geometric-mean row/column equilibration of a (B, m, n) batch.
-    Returns (row_scale (B, m), col_scale (B, n)), powers of two, such that
-    ``row_scale[:, :, None] * A * col_scale[:, None, :]`` has row/column
-    magnitude ranges centered near 1.  All-zero rows/columns get scale 1."""
+def _rescale(v: np.ndarray, big: np.ndarray, small: np.ndarray) -> np.ndarray:
+    """One geometric-mean step: divide each line's scale by the square root
+    of its largest times its smallest positive entry; a line with no
+    positive entry (``big`` 0 or -inf) keeps its scale."""
+    ok = np.isfinite(big) & (big > 0)
+    return v * np.where(ok, 1.0 / np.sqrt(np.where(ok, big * small, 1.0)), 1.0)
+
+
+def _scaled(w, r, s, rows, cols, out, tmp):
+    """``(w * r[rows]) * s[cols]`` into ``out``: the products of the dense
+    ``W * r[:, :, None] * s[:, None, :]`` at the pattern's entries, in the
+    same order of multiplication.  ``mode="clip"`` lets ``take`` write into
+    ``out`` unbuffered; every index is in range."""
+    np.take(r, rows, axis=0, out=out, mode="clip")
+    np.multiply(w, out, out=out)
+    np.take(s, cols, axis=0, out=tmp, mode="clip")
+    return np.multiply(out, tmp, out=out)
+
+
+def _by_degree(line: np.ndarray, n_lines: int):
+    """Order a pattern's entries by the degree of their line (its number of
+    entries), then by line, so that the lines of degree k hold one
+    contiguous block of k entries each.  Returns the order and, per
+    degree, (first entry of the block, k, its lines)."""
+    deg = np.bincount(line, minlength=n_lines)
+    order = np.lexsort((line, deg[line]))
+    groups, first = [], 0
+    for k in np.unique(deg[deg > 0]):
+        lines = np.flatnonzero(deg == k)
+        groups.append((first, k, lines))
+        first += len(lines) * k
+    return order, groups
+
+
+def _line_extremes(cur: np.ndarray, groups, n_lines: int):
+    """Largest and smallest positive entry of each line of a pattern, as
+    (n_lines, B) arrays: ``cur`` is (nnz, B), its entries in the order of
+    ``_by_degree``, whose ``groups`` it takes; it is nonnegative and holds
+    no NaN (a NaN of A makes its row's bound NaN, and ``canonicalize``
+    refuses or drops that row before it scales).  One reduction per degree
+    covers all the lines of that degree, over a contiguous block.  The
+    zeros are left out, as the dense passes' ``cur > 0`` mask leaves them
+    out; a line with no positive entry gets a ``big`` of 0 or -inf.
+    Overwrites the zeros of ``cur``."""
+    B = cur.shape[1]
+    big = np.full((n_lines, B), -np.inf)
+    small = np.full_like(big, np.inf)
+    for first, k, lines in groups:
+        block = cur[first:first + len(lines) * k]
+        big[lines] = block.reshape(len(lines), k, B).max(axis=1)
+    np.copyto(cur, np.inf, where=cur == 0)
+    for first, k, lines in groups:
+        block = cur[first:first + len(lines) * k]
+        small[lines] = block.reshape(len(lines), k, B).min(axis=1)
+    return big, small
+
+
+def _scale_in_place(A: np.ndarray, sp) -> Tuple[np.ndarray, np.ndarray]:
+    """Two passes of geometric-mean row/column equilibration of the
+    canonical batch ``A`` (B, m, n), C-contiguous as ``canonicalize``
+    builds it, applied in place.  Returns (row_scale (B, m), col_scale
+    (B, n)), powers of two, such that ``row_scale[:, :, None] * A *
+    col_scale[:, None, :]`` has row/column magnitude ranges centered near
+    1.  All-zero rows/columns get scale 1.
+
+    It runs over the union nonzero pattern of the batch, the positions
+    where any member is nonzero, held as (nnz, B) with its entries grouped
+    by row and, in a second copy, by column.  An entry off the pattern is
+    zero in every member and adds nothing to a line's largest or smallest
+    positive entry.  So the same products reach the same reductions as on
+    the dense array, and the scales and the scaled entries are the dense
+    algorithm's bit for bit.  The span ``sp`` gets the pattern's size
+    ``nnz`` and its ``density``."""
     B, m, n = A.shape
-    r = np.ones((B, m))
-    s = np.ones((B, n))
-    W = np.abs(A)
-    for _ in range(iters):
-        cur = W * r[:, :, None] * s[:, None, :]
-        nz = cur > 0
-        big = np.where(nz, cur, -np.inf).max(axis=2)
-        small = np.where(nz, cur, np.inf).min(axis=2)
-        ok = np.isfinite(big) & (big > 0)
-        r = r * np.where(ok, 1.0 / np.sqrt(np.where(ok, big * small, 1.0)), 1.0)
-        cur = W * r[:, :, None] * s[:, None, :]
-        nz = cur > 0
-        big = np.where(nz, cur, -np.inf).max(axis=1)
-        small = np.where(nz, cur, np.inf).min(axis=1)
-        ok = np.isfinite(big) & (big > 0)
-        s = s * np.where(ok, 1.0 / np.sqrt(np.where(ok, big * small, 1.0)), 1.0)
-    return _pow2(r), _pow2(s)
+    flat = A.reshape(B, m * n)
+    pos = np.flatnonzero((flat != 0).any(axis=0))
+    sp.set(nnz=len(pos), density=len(pos) / (m * n), path="pattern")
+    by_row, row_groups = _by_degree(pos // n, m)
+    pos = pos[by_row]
+    row, col = np.divmod(pos, n)
+    by_col, col_groups = _by_degree(col, n)
+    row_c, col_c = row[by_col], col[by_col]
+    vals = np.ascontiguousarray(flat[:, pos].T)
+    W = np.abs(vals)
+    Wc = W[by_col]
+    cur, tmp = np.empty_like(W), np.empty_like(W)
+    r = np.ones((m, B))
+    s = np.ones((n, B))
+    for _ in range(2):
+        r = _rescale(r, *_line_extremes(
+            _scaled(W, r, s, row, col, cur, tmp), row_groups, m))
+        s = _rescale(s, *_line_extremes(
+            _scaled(Wc, r, s, row_c, col_c, cur, tmp), col_groups, n))
+    r, s = _pow2(r), _pow2(s)
+    flat[:, pos] = _scaled(vals, r, s, row, col, cur, tmp).T
+    return np.ascontiguousarray(r.T), np.ascontiguousarray(s.T)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -630,9 +705,8 @@ def canonicalize(g: GeneralLPBatch, *, presolve: bool = True,
 
     row_scale = col_scale = None
     if scale and m_can and n_can:
-        with span("lp.canonicalize.scale"):
-            row_scale, col_scale = _equilibrate(A_can)
-            A_can = A_can * row_scale[:, :, None] * col_scale[:, None, :]
+        with span("lp.canonicalize.scale") as sp:
+            row_scale, col_scale = _scale_in_place(A_can, sp)
             b_can = b_can * row_scale
             c_can = c_can * col_scale
             # the solver variable is x_s = x / col_scale, so bounds scale too

@@ -6,6 +6,8 @@ must match the float64 oracle, recovered objectives must equal c.x in
 original coordinates bit-consistently across backends and pricing rules,
 and presolve scaling must never change exact-arithmetic statuses.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -15,6 +17,7 @@ from repro.core import (GeneralLPBatch, INFEASIBLE, LPBatch, OPTIMAL,
                         solve_batched, solve_batched_jax,
                         solve_batched_reference)
 from repro.core.forms import EQ, GE, LE, ensure_canonical
+from repro.io.mps import fixture_path, perturbed_batch, read_mps
 
 RNG = np.random.default_rng(11)
 
@@ -142,6 +145,111 @@ def test_scaling_is_pow2_and_invertible():
         assert np.all(fr == 0.5), "scales must be powers of two"
     back = lp_s.A / r[:, :, None] / s[:, None, :]
     np.testing.assert_array_equal(back, lp_u.A)
+
+
+def _dense_equilibrate(A, iters=2):
+    """The dense (B, m, n) equilibration, kept here as the oracle the
+    pattern path must match bit for bit."""
+    B, m, n = A.shape
+    r = np.ones((B, m))
+    s = np.ones((B, n))
+    W = np.abs(A)
+    for _ in range(iters):
+        cur = W * r[:, :, None] * s[:, None, :]
+        nz = cur > 0
+        big = np.where(nz, cur, -np.inf).max(axis=2)
+        small = np.where(nz, cur, np.inf).min(axis=2)
+        ok = np.isfinite(big) & (big > 0)
+        r = r * np.where(ok, 1.0 / np.sqrt(np.where(ok, big * small, 1.0)), 1.0)
+        cur = W * r[:, :, None] * s[:, None, :]
+        nz = cur > 0
+        big = np.where(nz, cur, -np.inf).max(axis=1)
+        small = np.where(nz, cur, np.inf).min(axis=1)
+        ok = np.isfinite(big) & (big > 0)
+        s = s * np.where(ok, 1.0 / np.sqrt(np.where(ok, big * small, 1.0)), 1.0)
+    pow2 = lambda v: np.exp2(np.round(np.log2(v)))  # noqa: E731
+    return pow2(r), pow2(s)
+
+
+def _sparse_general(rng, B, m, n, density, member_keep=1.0, spread=None):
+    """A feasible ``<=`` batch on one random pattern of ``density``; each
+    member keeps each pattern entry with probability ``member_keep``, and
+    ``spread`` = (lo, hi) draws magnitudes as 10**U(lo, hi)."""
+    support = rng.uniform(size=(m, n)) < density
+    support[np.arange(m), rng.integers(n, size=m)] = True
+    if spread is None:
+        mag = rng.uniform(0.5, 3.0, size=(B, m, n))
+    else:
+        mag = 10.0 ** rng.uniform(*spread, size=(B, m, n))
+    A = mag * rng.choice([-1.0, 1.0], size=(B, m, n))
+    A *= support & (rng.uniform(size=(B, m, n)) < member_keep)
+    return GeneralLPBatch.from_arrays(
+        A, [LE] * m, rng.uniform(1.0, 2.0, size=(B, m)),
+        ub=rng.uniform(1.0, 5.0, size=(B, n)),
+        c=rng.uniform(-1.0, 1.0, size=(B, n)))
+
+
+def _empty_row_and_column():
+    g = _sparse_general(np.random.default_rng(24), 64, 20, 18, 0.2)
+    A = g.A.copy()
+    A[:, 7] = 0.0
+    A[:, :, 11] = 0.0
+    return dataclasses.replace(g, A=A), {"presolve": False, "scale": True}
+
+
+SCALE_CASES = {
+    "afiro_perturbed": lambda: (perturbed_batch(
+        read_mps(fixture_path("afiro")), 256,
+        np.random.default_rng(21)), {}),
+    "sc50b_like": lambda: (perturbed_batch(
+        read_mps(fixture_path("sc50b_like")), 32,
+        np.random.default_rng(22)), {}),
+    "dense_random": lambda: (GeneralLPBatch.from_arrays(
+        np.random.default_rng(23).uniform(-3.0, 3.0, size=(128, 12, 10)),
+        [LE, GE, EQ] * 4, np.ones((128, 12)), ub=np.full((128, 10), 4.0),
+        c=np.ones((128, 10))), {}),
+    "member_patterns_differ": lambda: (_sparse_general(
+        np.random.default_rng(25), 96, 24, 20, 0.25, member_keep=0.7), {}),
+    "empty_row_and_column": _empty_row_and_column,
+    "single_lp": lambda: (_sparse_general(
+        np.random.default_rng(26), 1, 30, 28, 0.15), {}),
+    "magnitudes_1e-8_to_1e8": lambda: (_sparse_general(
+        np.random.default_rng(27), 80, 22, 26, 0.3, spread=(-8.0, 8.0)), {}),
+    "no_nonzeros": lambda: (GeneralLPBatch.from_arrays(
+        np.zeros((16, 5, 4)), [LE] * 5, np.ones((16, 5)),
+        ub=np.ones((16, 4)), c=np.ones((16, 4))),
+        {"presolve": False, "scale": True}),
+}
+
+def _same_bits(got, want):
+    if got is None or want is None:
+        return got is None and want is None
+    got, want = np.asarray(got), np.asarray(want)
+    return (got.shape == want.shape and got.dtype == want.dtype
+            and np.array_equal(got.view(np.uint64), want.view(np.uint64)))
+
+
+@pytest.mark.parametrize("case", list(SCALE_CASES))
+def test_scaling_matches_dense_oracle_bit_for_bit(case):
+    """The equilibration over the shared pattern gives the dense
+    algorithm's scales, and the scaled A, b, c and ub, to the bit (signed
+    zeros included)."""
+    g, kw = SCALE_CASES[case]()
+    raw, _ = canonicalize(g, **{**kw, "scale": False})
+    r, s = _dense_equilibrate(np.asarray(raw.A))
+    lp, rec = canonicalize(g, **kw)
+    assert _same_bits(rec.row_scale, r) and _same_bits(rec.col_scale, s)
+    assert _same_bits(lp.A, raw.A * r[:, :, None] * s[:, None, :])
+    assert _same_bits(lp.b, raw.b * r)
+    assert _same_bits(lp.c, raw.c * s)
+    assert _same_bits(lp.ub, None if raw.ub is None else raw.ub / s)
+    if case == "empty_row_and_column":
+        assert (rec.row_scale[:, 7] == 1.0).all()
+        assert (rec.col_scale[:, 11] == 1.0).all()
+    if case == "magnitudes_1e-8_to_1e8":
+        assert np.ptp(np.log2(rec.row_scale)) > 20
+    if case == "no_nonzeros":
+        assert (rec.row_scale == 1.0).all() and (rec.col_scale == 1.0).all()
 
 
 def test_ensure_canonical_passthrough():

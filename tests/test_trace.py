@@ -7,8 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (random_lp_batch, solve_batched,
+from repro.core import (GeneralLPBatch, random_lp_batch, solve_batched,
                         solve_batched_compacted)
+from repro.core.forms import ensure_canonical
 from repro.core.simplex import _host_cast, _put
 from repro.io.mps import fixture_path, perturbed_batch, read_mps
 from repro.obs import SpanTracer, span, tagged
@@ -133,6 +134,30 @@ def test_explicit_tracer_records_the_spans_inside():
         GRANDCHILDREN["lp.canonicalize"]
     dispatch = names.index("lp.dispatch")
     assert tracer.roots[dispatch].args["B"] == 8
+
+
+@pytest.mark.parametrize("kind,B,nnz", [
+    ("afiro", 64, 118),     # 10.5% of the 35 x 32 canonical A
+    ("afiro", 1, 118),
+    ("dense", 256, 12 * 10),
+])
+def test_scale_span_records_the_pattern(kind, B, nnz):
+    rng = np.random.default_rng(B)
+    if kind == "afiro":
+        batch = perturbed_batch(read_mps(fixture_path("afiro")), B, rng)
+    else:
+        batch = GeneralLPBatch.from_arrays(
+            rng.uniform(1.0, 2.0, size=(B, 12, 10)), ["L"] * 12,
+            np.ones((B, 12)), c=np.ones((B, 10)))
+    tracer = SpanTracer()
+    with tracer.active():
+        lp, _ = ensure_canonical(batch)
+    (can,) = tracer.roots
+    assert can.name == "lp.canonicalize"
+    scale = can.children[-1]
+    assert scale.name == "lp.canonicalize.scale"
+    assert scale.args == {"nnz": nnz, "density": nnz / (lp.m * lp.n),
+                          "path": "pattern"}
 
 
 def test_tags_and_late_args():
