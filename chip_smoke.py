@@ -241,8 +241,7 @@ def engines_and_kernels(s: Smoke, d28) -> None:
 
 def four_chips(s: Smoke) -> None:
     from repro.core import solve_batched, solve_pjit, solve_shard_map
-    from repro.core.distributed import shard_batch
-    from repro.distributed.sharding import make_mesh
+    from repro.core.distributed import make_mesh, shard_batch
 
     if len(s.jax.devices()) < 4:
         raise SmokeFailure(f"--chips 4 needs 4 devices, JAX reports "

@@ -26,13 +26,18 @@ and the solve resumes — per-shard exit *within* a segment, per-block exit
 *across* segments.
 
 Both shard the batch axis over every mesh axis (LP solving has no model
-dimension to shard).
+dimension to shard).  Called without a mesh, ``solve_shard_map`` builds
+``make_mesh()``: every local device on one ``("data",)`` axis.  Its one-shot
+solve is ``batching.solve_batched`` with a sharded chunk solver, so it
+canonicalises, plans chunks against every chip's memory, recovers and
+writes the ``lp.*`` spans as the one-chip path does.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,10 +47,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..obs.report import report_from_counters
 from ..obs.telemetry import tel_to_numpy
 from ..obs.trace import span
+from .batching import solve_batched
 from .forms import ensure_canonical, finish_result
-from .lp import (LPBatch, LPResult, OPTIMAL, ITERATION_LIMIT,
-                 canonicalize_backend, default_max_iters)
-from .simplex import solve_two_phase
+from .lp import LPBatch, LPResult, canonicalize_backend, default_max_iters
+from .simplex import _host_cast, solve_two_phase
 from .compaction import (
     CompactionConfig, CompactionState, JaxBackend, resolve_compact_threshold,
     run_schedule, segment_phase1, segment_phase2,
@@ -57,6 +62,17 @@ from .revised import (
 from .pdhg import (
     PdhgBackend, PdhgState, default_pdhg_max_iters, segment_pdhg, solve_pdhg,
 )
+
+
+def make_mesh(shape: Optional[Tuple[int, ...]] = None,
+              axes: Tuple[str, ...] = ("data",)) -> Mesh:
+    """A mesh over the first ``prod(shape)`` devices; with no ``shape``,
+    every device on one axis (the data-parallel mesh ``solve_shard_map``
+    builds when it is given none)."""
+    if shape is None:
+        shape = (len(jax.devices()),)
+    devices = np.asarray(jax.devices()[: int(np.prod(shape))]).reshape(shape)
+    return Mesh(devices, axes)
 
 
 def _pad_batch(batch: LPBatch, multiple: int):
@@ -120,19 +136,27 @@ def _backend_defaults(backend: str, max_iters, tol, m: int, n: int, dtype):
 
 
 
+def _leaves(batch: LPBatch) -> tuple:
+    """The arrays the engines take: (A, b, c, ub)."""
+    return batch.A, batch.b, batch.c, batch.upper_bounds()
+
+
+def _put(host: list, mesh: Mesh, dtype) -> list:
+    """Place each host-cast array (``simplex._host_cast``) batch-sharded
+    over every mesh axis, so each device receives only its own LPs."""
+    shard = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+    put = [jax.device_put(h, shard) for h in host]
+    return [d if d.dtype == dtype else d.astype(dtype) for d in put]
+
+
 def shard_batch(batch: LPBatch, mesh: Mesh, dtype):
-    """Pad the batch to a multiple of the device count and place its
-    (A, b, c, ub) arrays batch-sharded over every mesh axis, so each device
-    receives only its own LPs.  Returns ``(A, b, c, ub, axes, orig_B,
-    padded)``."""
-    axes = tuple(mesh.axis_names)
-    n_dev = int(np.prod(mesh.devices.shape))
-    padded, orig = _pad_batch(batch, n_dev)
-    shard = NamedSharding(mesh, P(axes))
-    A, b, c, ub = (jax.device_put(np.asarray(v, dtype), shard)
-                   for v in (padded.A, padded.b, padded.c,
-                             padded.upper_bounds()))
-    return A, b, c, ub, axes, orig, padded
+    """Pad the batch to a multiple of the device count, cast it on the
+    host and place it sharded (`_put`).  Returns ``(A, b, c, ub, axes,
+    orig_B, padded)``."""
+    padded, orig = _pad_batch(batch, mesh.size)
+    dt = jax.dtypes.canonicalize_dtype(dtype)
+    A, b, c, ub = _put([_host_cast(a, dt) for a in _leaves(padded)], mesh, dt)
+    return A, b, c, ub, tuple(mesh.axis_names), orig, padded
 
 
 def solve_pjit(batch: LPBatch, mesh: Mesh, *, dtype=jnp.float32,
@@ -322,7 +346,80 @@ class _PdhgShardMapBackend(PdhgBackend):
         return state, int(np.max(np.asarray(it)))
 
 
-def solve_shard_map(batch: LPBatch, mesh: Mesh, *, dtype=jnp.float32,
+@functools.lru_cache(maxsize=None)
+def _shard_map_solver(mesh: Mesh, m, n, max_iters, tol, feas_tol, pricing,
+                      backend, refactor_period, telemetry):
+    """The jitted one-shot solve under shard_map: each chip runs
+    `_solve_local` over its own LPs, with its own while-loop.  Built once
+    per mesh and solve settings, so repeated calls reuse the program."""
+    spec = P(tuple(mesh.axis_names))
+    local = functools.partial(_solve_local, m=m, n=n, max_iters=max_iters,
+                              tol=tol, feas_tol=feas_tol, pricing=pricing,
+                              backend=backend, refactor_period=refactor_period,
+                              telemetry=telemetry)
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(spec, spec, spec, spec),
+        # one extra batch-sharded prefix entry covers every telemetry lane
+        out_specs=(spec,) * (7 if telemetry else 6),
+        check_vma=False,
+    ))
+
+
+def _solve_shards(batch: LPBatch, *, mesh: Mesh, dtype=jnp.float32,
+                  tol: Optional[float] = None, feas_tol: float = 1e-5,
+                  max_iters: Optional[int] = None, pricing: str = "dantzig",
+                  backend: str = "tableau",
+                  refactor_period: Optional[int] = None,
+                  telemetry: bool = False) -> LPResult:
+    """One chunk of the one-shot `solve_shard_map`, the chunk solver that
+    `solve_batched` calls: pad to a multiple of the chip count, cast on the
+    host, put batch-sharded, run `_shard_map_solver`, wait, fetch, unpad.
+    ``lp.d2h`` carries ``shard_max_iters``: the most iterations returned in
+    each chip's contiguous block, the pivots that chip's loop ran."""
+    m, n = batch.m, batch.n
+    max_iters, tol = _backend_defaults(backend, max_iters, tol, m, n, dtype)
+    shards = mesh.size
+    padded, orig = _pad_batch(batch, shards)
+    dt = jax.dtypes.canonicalize_dtype(dtype)
+    with span("lp.h2d", shards=shards) as h2d:
+        leaves = _leaves(padded)
+        with span("lp.h2d.cast"):
+            host = [_host_cast(a, dt) for a in leaves]
+        with span("lp.h2d.put"):
+            dev = _put(host, mesh, dt)
+        h2d.set(bytes_in=sum(getattr(a, "nbytes", 0) for a in leaves),
+                bytes_out=sum(h.nbytes for h in host))
+    # free the host copies now, as solve_batched_jax does
+    del leaves, host
+    fn = _shard_map_solver(mesh, m, n, int(max_iters), float(tol),
+                           float(feas_tol), pricing, backend, refactor_period,
+                           bool(telemetry))
+    t0 = time.perf_counter()
+    with span("lp.dispatch", B=padded.batch, m=m, n=n, shards=shards):
+        out = fn(*dev)
+    with span("lp.wait"):
+        jax.block_until_ready(out)
+    wall_s = time.perf_counter() - t0
+    with span("lp.d2h") as d2h:
+        x, obj, status, iters, y, z = (np.asarray(a) for a in out[:6])
+        fetched = [x, obj, status, iters, y, z]
+        stats = None
+        if telemetry:
+            counters = tel_to_numpy(out[6])
+            fetched += counters.values()
+            stats = report_from_counters(
+                {k: v[:orig] for k, v in counters.items()}, wall_s=wall_s,
+                backend=backend)
+        d2h.set(arrays=len(fetched), bytes=sum(a.nbytes for a in fetched),
+                shard_max_iters=iters.reshape(shards, -1).max(axis=1).tolist())
+    return LPResult(x=x[:orig], objective=obj[:orig], status=status[:orig],
+                    iterations=iters[:orig], y=y[:orig], z=z[:orig],
+                    stats=stats)
+
+
+def solve_shard_map(batch: LPBatch, mesh: Optional[Mesh] = None, *,
+                    dtype=jnp.float32,
                     tol: Optional[float] = None, feas_tol: float = 1e-5,
                     max_iters: Optional[int] = None, lower_only: bool = False,
                     segment_k: Optional[int] = None,
@@ -331,25 +428,28 @@ def solve_shard_map(batch: LPBatch, mesh: Mesh, *, dtype=jnp.float32,
                     backend: str = "tableau",
                     refactor_period: Optional[int] = None,
                     presolve: bool = True, scale: Optional[bool] = None,
-                    telemetry: bool = False, tracer=None):
+                    telemetry: bool = False, tracer=None,
+                    device_bytes: Optional[int] = None):
     """Per-shard termination: each chip solves its local LPs to completion
-    independently (no cross-chip sync per pivot).
+    independently (no cross-chip sync per pivot).  ``mesh=None`` builds
+    `make_mesh()`: every local device, data-parallel.
 
-    ``segment_k=None`` (default) keeps the original one-shot semantics.
-    ``segment_k=K`` runs the solve in K-pivot segments through the active-set
-    compaction scheduler (see module docstring); results are identical, work
-    shrinks with the survivor count (``compact_threshold=None`` derives the
-    gather eagerness from `auto_compact_threshold`).  ``pricing`` selects the
-    entering-column rule (core/pricing.py) in both modes, and
-    ``backend="revised"`` the basis-factor engine (core/revised.py).
-    GeneralLPBatch inputs canonicalize on the host before sharding and
-    recover after the gather, in both the one-shot and segmented modes."""
+    ``segment_k=None`` (default) is the one-shot solve: `solve_batched`
+    with `_solve_shards` as its chunk solver, so the batch is canonicalised,
+    planned against every chip's memory (``device_bytes`` per chip, default
+    the limit the first device reports) and run in chunks, each spread over
+    the mesh.  ``segment_k=K`` runs the solve in K-pivot segments through
+    the active-set compaction scheduler (see module docstring); results are
+    identical, work shrinks with the survivor count
+    (``compact_threshold=None`` derives the gather eagerness from
+    `auto_compact_threshold`).  ``pricing`` selects the entering-column rule
+    (core/pricing.py) in both modes, and ``backend="revised"`` the
+    basis-factor engine (core/revised.py).  GeneralLPBatch inputs
+    canonicalize on the host before sharding and recover after the gather,
+    in both the one-shot and segmented modes."""
     canonicalize_backend(backend)
-    batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale,
-                                  tracer=tracer)
-    m, n = batch.m, batch.n
-    max_iters, tol = _backend_defaults(backend, max_iters, tol, m, n, dtype)
-
+    if mesh is None:
+        mesh = make_mesh()
     if segment_k is not None and lower_only:
         raise ValueError(
             "segment_k and lower_only cannot be combined: the segmented "
@@ -359,79 +459,61 @@ def solve_shard_map(batch: LPBatch, mesh: Mesh, *, dtype=jnp.float32,
         raise ValueError(
             "stats_out requires segment_k: the one-shot solve has no "
             "segment accounting to record")
+    if segment_k is None and not lower_only:
+        with (tracer.active() if tracer is not None
+              else contextlib.nullcontext()):
+            return solve_batched(
+                batch, solver=_solve_shards, device_bytes=device_bytes,
+                n_devices=mesh.size, pricing=pricing, backend=backend,
+                presolve=presolve, scale=scale, mesh=mesh, dtype=dtype,
+                tol=tol, feas_tol=feas_tol, max_iters=max_iters,
+                refactor_period=refactor_period, telemetry=telemetry)
 
-    if segment_k is not None:
-        budget = max_iters
-        if backend == "revised":
-            runner = _RevisedShardMapBackend(
-                mesh, m, n, tol, feas_tol, dtype, pricing=pricing,
-                refactor_period=refactor_period)
-        elif backend == "pdhg":
-            from .pdhg import _check_pdhg_pricing
-            _check_pdhg_pricing(pricing)
-            runner = _PdhgShardMapBackend(mesh, m, n, tol, dtype)
-            # the scheduler's step unit for pdhg is one check round
-            budget = -(-max_iters // runner.check_every)
-        else:
-            runner = _ShardMapBackend(mesh, m, n, tol, feas_tol, dtype,
-                                      pricing=pricing)
-        padded, orig_B = _pad_batch(batch, runner.pad_multiple)
-        state = runner.init(jnp.asarray(padded.A, dtype),
-                            jnp.asarray(padded.b, dtype),
-                            jnp.asarray(padded.c, dtype),
-                            ub=jnp.asarray(padded.upper_bounds(), dtype),
-                            telemetry=telemetry)
-        B_pad = padded.batch
-        orig = np.concatenate(
-            [np.arange(orig_B), np.full(B_pad - orig_B, -1)]).astype(np.int64)
-        # padding LPs are not real work: retire them before the first segment
-        state = runner.deactivate(state, orig >= 0)
-        cfg = CompactionConfig(
-            segment_k=segment_k,
-            compact_threshold=resolve_compact_threshold(compact_threshold,
-                                                        segment_k),
-            pad_multiple=runner.pad_multiple)
-        return finish_result(rec, run_schedule(runner, state, orig, orig_B, n,
-                                               max_iters=budget, config=cfg,
-                                               stats_out=stats_out,
-                                               tracer=tracer),
-                             tracer=tracer)
-
-    A, b, c, ub, axes, orig, _ = shard_batch(batch, mesh, dtype)
-    spec = P(axes)
-
-    local = functools.partial(_solve_local, m=m, n=n, max_iters=max_iters,
-                              tol=tol, feas_tol=feas_tol, pricing=pricing,
-                              backend=backend, refactor_period=refactor_period,
-                              telemetry=telemetry)
-    fn = jax.jit(jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(spec, spec, spec, spec),
-        # one extra batch-sharded prefix entry covers every telemetry lane
-        out_specs=(spec,) * (7 if telemetry else 6),
-        check_vma=False,
-    ))
+    batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale,
+                                  tracer=tracer)
+    m, n = batch.m, batch.n
+    max_iters, tol = _backend_defaults(backend, max_iters, tol, m, n, dtype)
     if lower_only:
-        return fn.lower(jax.ShapeDtypeStruct(A.shape, A.dtype),
-                        jax.ShapeDtypeStruct(b.shape, b.dtype),
-                        jax.ShapeDtypeStruct(c.shape, c.dtype),
-                        jax.ShapeDtypeStruct(ub.shape, ub.dtype))
-    t0 = time.perf_counter()
-    with span("lp.dispatch", tracer, backend=backend, B=batch.batch, m=m,
-              n=n):
-        out = fn(A, b, c, ub)
-        x, obj, status, iters, y, z = out[:6]
-        stats = None
-        if telemetry:
-            jax.block_until_ready(out[6])
-            counters = {k: v[:orig]
-                        for k, v in tel_to_numpy(out[6]).items()}
-            stats = report_from_counters(counters,
-                                         wall_s=time.perf_counter() - t0,
-                                         backend=backend)
-    res = LPResult(x=np.asarray(x)[:orig], objective=np.asarray(obj)[:orig],
-                   status=np.asarray(status)[:orig],
-                   iterations=np.asarray(iters)[:orig],
-                   y=np.asarray(y)[:orig], z=np.asarray(z)[:orig],
-                   stats=stats)
-    return finish_result(rec, res, tracer=tracer)
+        fn = _shard_map_solver(mesh, m, n, int(max_iters), float(tol),
+                               float(feas_tol), pricing, backend,
+                               refactor_period, bool(telemetry))
+        B = -(-batch.batch // mesh.size) * mesh.size
+        dt = jax.dtypes.canonicalize_dtype(dtype)
+        return fn.lower(*(jax.ShapeDtypeStruct(shape, dt) for shape in
+                          ((B, m, n), (B, m), (B, n), (B, n))))
+
+    budget = max_iters
+    if backend == "revised":
+        runner = _RevisedShardMapBackend(
+            mesh, m, n, tol, feas_tol, dtype, pricing=pricing,
+            refactor_period=refactor_period)
+    elif backend == "pdhg":
+        from .pdhg import _check_pdhg_pricing
+        _check_pdhg_pricing(pricing)
+        runner = _PdhgShardMapBackend(mesh, m, n, tol, dtype)
+        # the scheduler's step unit for pdhg is one check round
+        budget = -(-max_iters // runner.check_every)
+    else:
+        runner = _ShardMapBackend(mesh, m, n, tol, feas_tol, dtype,
+                                  pricing=pricing)
+    padded, orig_B = _pad_batch(batch, runner.pad_multiple)
+    state = runner.init(jnp.asarray(padded.A, dtype),
+                        jnp.asarray(padded.b, dtype),
+                        jnp.asarray(padded.c, dtype),
+                        ub=jnp.asarray(padded.upper_bounds(), dtype),
+                        telemetry=telemetry)
+    B_pad = padded.batch
+    orig = np.concatenate(
+        [np.arange(orig_B), np.full(B_pad - orig_B, -1)]).astype(np.int64)
+    # padding LPs are not real work: retire them before the first segment
+    state = runner.deactivate(state, orig >= 0)
+    cfg = CompactionConfig(
+        segment_k=segment_k,
+        compact_threshold=resolve_compact_threshold(compact_threshold,
+                                                    segment_k),
+        pad_multiple=runner.pad_multiple)
+    return finish_result(rec, run_schedule(runner, state, orig, orig_B, n,
+                                           max_iters=budget, config=cfg,
+                                           stats_out=stats_out,
+                                           tracer=tracer),
+                         tracer=tracer)

@@ -22,12 +22,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core.distributed import make_mesh  # noqa: F401  (re-exported)
 from repro.models.config import ModelConfig
-
-
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    devices = np.asarray(jax.devices()[: int(np.prod(shape))]).reshape(shape)
-    return Mesh(devices, axes)
 
 
 class Sharder:
