@@ -50,7 +50,8 @@ from ..obs.trace import span
 from .batching import solve_batched
 from .forms import ensure_canonical, finish_result
 from .lp import LPBatch, LPResult, canonicalize_backend, default_max_iters
-from .simplex import _host_cast, solve_two_phase
+from .simplex import (_HOST_STAGING, _host_cast, _upper_bounds,
+                      solve_two_phase)
 from .compaction import (
     CompactionConfig, CompactionState, JaxBackend, resolve_compact_threshold,
     run_schedule, segment_phase1, segment_phase2,
@@ -137,8 +138,9 @@ def _backend_defaults(backend: str, max_iters, tol, m: int, n: int, dtype):
 
 
 def _leaves(batch: LPBatch) -> tuple:
-    """The arrays the engines take: (A, b, c, ub)."""
-    return batch.A, batch.b, batch.c, batch.upper_bounds()
+    """The arrays the engines take: (A, b, c, ub); a batch with no bounds
+    gives ub as a broadcast of +inf (`simplex._upper_bounds`)."""
+    return batch.A, batch.b, batch.c, _upper_bounds(batch)
 
 
 def _put(host: list, mesh: Mesh, dtype) -> list:
@@ -382,24 +384,28 @@ def _solve_shards(batch: LPBatch, *, mesh: Mesh, dtype=jnp.float32,
     shards = mesh.size
     padded, orig = _pad_batch(batch, shards)
     dt = jax.dtypes.canonicalize_dtype(dtype)
-    with span("lp.h2d", shards=shards) as h2d:
-        leaves = _leaves(padded)
-        with span("lp.h2d.cast"):
-            host = [_host_cast(a, dt) for a in leaves]
-        with span("lp.h2d.put"):
-            dev = _put(host, mesh, dt)
-        h2d.set(bytes_in=sum(getattr(a, "nbytes", 0) for a in leaves),
-                bytes_out=sum(h.nbytes for h in host))
-    # free the host copies now, as solve_batched_jax does
-    del leaves, host
-    fn = _shard_map_solver(mesh, m, n, int(max_iters), float(tol),
-                           float(feas_tol), pricing, backend, refactor_period,
-                           bool(telemetry))
-    t0 = time.perf_counter()
-    with span("lp.dispatch", B=padded.batch, m=m, n=n, shards=shards):
-        out = fn(*dev)
-    with span("lp.wait"):
-        jax.block_until_ready(out)
+    with _HOST_STAGING.lease() as cast:
+        with span("lp.h2d", shards=shards) as h2d:
+            leaves = _leaves(padded)
+            with span("lp.h2d.cast") as cast_span:
+                host, cast_args = cast([(a, dt) for a in leaves])
+                cast_span.set(**cast_args)
+            with span("lp.h2d.put"):
+                dev = _put(host, mesh, dt)
+            h2d.set(bytes_in=sum(getattr(a, "nbytes", 0) for a in leaves),
+                    bytes_out=sum(h.nbytes for h in host))
+        # free the host copies now, as solve_batched_jax does
+        del leaves, host
+        fn = _shard_map_solver(mesh, m, n, int(max_iters), float(tol),
+                               float(feas_tol), pricing, backend,
+                               refactor_period, bool(telemetry))
+        t0 = time.perf_counter()
+        with span("lp.dispatch", B=padded.batch, m=m, n=n, shards=shards):
+            out = fn(*dev)
+        with span("lp.wait"):
+            jax.block_until_ready(out)
+        # every input the outputs read has been read (solve_batched_jax)
+        del dev
     wall_s = time.perf_counter() - t0
     with span("lp.d2h") as d2h:
         x, obj, status, iters, y, z = (np.asarray(a) for a in out[:6])

@@ -138,3 +138,38 @@ def test_spans_and_shard_max_iters():
         print("SPANS-OK", want)
     """)
     assert "SPANS-OK" in out
+
+
+def test_shards_cast_into_the_kept_buffer():
+    out = _run("""
+        from repro.core import simplex
+        from repro.core.distributed import _solve_shards, make_mesh
+        from repro.obs import SpanTracer
+        mesh = make_mesh()
+        other = random_lp_batch(np.random.default_rng(4), B=37, m=12, n=8,
+                                feasible_start=False)
+
+        def solve(b):
+            tr = SpanTracer()
+            with tr.active():
+                res = _solve_shards(b, mesh=mesh)
+            (cast,) = [s for root in tr.roots for s in root.walk()
+                       if s.name == "lp.h2d.cast"]
+            return res, cast.args["staging"]
+
+        first, s1 = solve(batch)
+        kept = {f: getattr(first, f).copy() for f in FIELDS}
+        second, s2 = solve(other)
+        assert (s1, s2) == ("grown", "reused"), (s1, s2)
+        for f in FIELDS:      # the second call left the first result alone
+            assert np.array_equal(getattr(first, f), kept[f],
+                                  equal_nan=True), f
+        # the fresh cast, as before the buffer: another call holds it
+        with simplex._HOST_STAGING.lease():
+            (f1, t1), (f2, t2) = solve(batch), solve(other)
+        assert (t1, t2) == ("fresh", "fresh"), (t1, t2)
+        same(first, f1)
+        same(second, f2)
+        print("STAGING-OK")
+    """)
+    assert "STAGING-OK" in out
