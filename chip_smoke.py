@@ -6,7 +6,7 @@
 
 Drives the normal entry points (``repro.core.solve_batched``,
 ``repro.kernels.ops.solve_batched_pallas``; with ``--chips 4``
-``solve_shard_map`` and ``solve_pjit``) on the repo's own deployment,
+``solve_shard_map``) on the repo's own deployment,
 ``configs/paper_lp.py`` ``WORKLOADS``, at its published batch sizes, with
 data made from ``--seed``.  Every phase is checked against the float64
 oracle (``solve_batched_reference``) on a seeded subset of 256 LPs, and
@@ -240,15 +240,17 @@ def engines_and_kernels(s: Smoke, d28) -> None:
 
 
 def four_chips(s: Smoke) -> None:
-    from repro.core import solve_batched, solve_pjit, solve_shard_map
-    from repro.core.distributed import make_mesh, shard_batch
+    from repro.core import solve_batched, solve_shard_map, transfer
+    from repro.core.distributed import make_mesh
 
     if len(s.jax.devices()) < 4:
         raise SmokeFailure(f"--chips 4 needs 4 devices, JAX reports "
                            f"{len(s.jax.devices())}")
     mesh = make_mesh((4,), ("data",))
     d28 = s.data("lp_28d_100k", 100_000, 0)
-    A = shard_batch(d28, mesh, s.np.float32)[0]
+    f32 = s.np.dtype(s.np.float32)
+    # the put every mesh solve makes of its host-cast leaves
+    A = transfer.put_sharded(s.np.asarray(d28.A, f32), mesh, f32)
     rows = {sh.device.id: sh.data.shape[0] for sh in A.addressable_shards}
     print(f"spread lp_28d_100k over {len(rows)} devices: rows per device "
           f"{rows}", flush=True)
@@ -258,12 +260,10 @@ def four_chips(s: Smoke) -> None:
                 lambda: solve_batched(d28), n_lps=d28.batch, key="28",
                 batch=d28, tol=SIMPLEX_TOL)
     fields = ("status", "iterations", "objective", "x")
-    for name, solve in (("solve_shard_map", solve_shard_map),
-                        ("solve_pjit", solve_pjit)):
-        res = s.run(f"{name} lp_28d_100k (4 chips)",
-                    lambda: solve(d28, mesh), n_lps=d28.batch, key="28",
-                    batch=d28, tol=SIMPLEX_TOL)
-        s.same(f"{name} lp_28d_100k (4 chips)", res, one, fields)
+    res = s.run("solve_shard_map lp_28d_100k (4 chips)",
+                lambda: solve_shard_map(d28, mesh), n_lps=d28.batch, key="28",
+                batch=d28, tol=SIMPLEX_TOL)
+    s.same("solve_shard_map lp_28d_100k (4 chips)", res, one, fields)
 
 
 def main(argv=None) -> int:

@@ -22,7 +22,12 @@ Public API:
                                            (B, nnz) values; PDHG matvecs
                                            scale with nnz, not m*n
     solve_hyperbox                       — box-LP closed form (Sec. 5.6)
-    solve_pjit / solve_shard_map         — multi-chip batch-parallel solvers
+    solve_shard_map                      — the batch spread over a mesh,
+                                           each chip its own while loop
+                                           (``mesh=`` on solve_batched and
+                                           the engines; core/transfer.py
+                                           moves every one-shot solve's
+                                           inputs and answers)
     expert_capacity_lp                   — MoE integration (LP router)
     PRICING_RULES / ALL_PRICING          — pluggable pivot pricing
                                            (``pricing=`` on every solve_*):
@@ -74,7 +79,7 @@ from .reference import (  # noqa: F401
     random_lp_batch, random_sparse_lp_batch, solve_batched_reference,
     solve_batched_reference_detailed, solve_dual_reference,
 )
-from .distributed import solve_pjit, solve_shard_map  # noqa: F401
+from .distributed import solve_shard_map  # noqa: F401
 from .lp_router import expert_capacity_lp  # noqa: F401
 from .branch_bound import (  # noqa: F401
     BnBResult, branch_and_bound, safe_dual_bound,

@@ -99,7 +99,7 @@ def difficulty_proxy(batch: LPBatch) -> np.ndarray:
 def solve_batched(batch: LPBatch, *, solver: Optional[Callable] = None,
                   chunk_size: Optional[int] = None,
                   device_bytes: Optional[int] = None,
-                  n_devices: int = 1, sort_by_difficulty: bool = False,
+                  mesh=None, sort_by_difficulty: bool = False,
                   compaction: bool = False, pricing: str = "dantzig",
                   backend: str = "tableau",
                   presolve: bool = True, scale: Optional[bool] = None,
@@ -107,8 +107,12 @@ def solve_batched(batch: LPBatch, *, solver: Optional[Callable] = None,
                   pad_to_bucket: bool = False,
                   **solver_kwargs) -> LPResult:
     """Chunked batched solve (Algorithm 1). ``solver`` defaults to the pure
-    JAX lockstep solver; kernels.ops.solve_batched_pallas and
-    core.distributed solvers are drop-in.
+    JAX lockstep solver; kernels.ops.solve_batched_pallas is drop-in.
+
+    ``mesh`` spreads every chunk over the mesh's devices, each with its own
+    while loop (the engines' ``mesh=``, `transfer.sharded_solve`), and
+    plans chunks against all of their memory; it takes the built-in
+    one-shot engines only: no ``solver``, ``compaction`` or ``warm``.
 
     ``sort_by_difficulty`` (beyond-paper optimization): lockstep SIMD chunks
     pay max-pivots-over-chunk; reordering LPs by ``difficulty_proxy`` so
@@ -137,9 +141,9 @@ def solve_batched(batch: LPBatch, *, solver: Optional[Callable] = None,
     requested (solve_batched_pallas does).
 
     ``chunk_size=None`` plans chunks from ``device_bytes`` (default: the
-    limit the first device reports, `device_memory_bytes`) and the
-    engine's compiled bytes per LP; a batch that does not fit is split into
-    equal chunks, so every chunk runs one compiled program.
+    limit the first device reports, `device_memory_bytes`; per device on a
+    mesh) and the engine's compiled bytes per LP; a batch that does not fit
+    is split into equal chunks, so every chunk runs one compiled program.
 
     A ``GeneralLPBatch`` (core/forms.py) is canonicalized *once* up front —
     chunking, sorting and memory planning all operate on the canonical
@@ -169,6 +173,12 @@ def solve_batched(batch: LPBatch, *, solver: Optional[Callable] = None,
     with span("lp.solve", solve_id=next(_SOLVE_IDS), B=batch.batch,
               m=batch.m, n=batch.n):
         canonicalize_backend(backend)
+        if mesh is not None and (solver is not None or compaction
+                                 or warm is not None):
+            raise ValueError(
+                "mesh= runs the built-in one-shot engines: it takes no "
+                "solver=, compaction=True or warm= (use "
+                "solve_shard_map(segment_k=) for compaction over a mesh)")
         batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale)
         warm = prepare_warm(warm, rec, batch)
         if solver is None:
@@ -180,6 +190,8 @@ def solve_batched(batch: LPBatch, *, solver: Optional[Callable] = None,
                 solver = (solve_batched_compacted if compaction
                           else solve_batched_jax)
             solver_kwargs["pricing"] = pricing
+            if mesh is not None:
+                solver_kwargs["mesh"] = mesh
         elif compaction or pricing != "dantzig" or backend != "tableau" \
                 or warm is not None:
             # only introspect when a kwarg actually needs forwarding, so
@@ -257,7 +269,8 @@ def solve_batched(batch: LPBatch, *, solver: Optional[Callable] = None,
                 if device_bytes is None:
                     device_bytes = device_memory_bytes()
                 chunk_size = B if device_bytes is None else max_chunk_size(
-                    batch, device_bytes, n_devices, backend=backend)
+                    batch, device_bytes, 1 if mesh is None else mesh.size,
+                    backend=backend)
                 if chunk_size < B:
                     chunk_size = -(-B // -(-B // chunk_size))
             n_chunks = 1 if chunk_size >= B else math.ceil(B / chunk_size)

@@ -26,7 +26,7 @@ unsegmented solver.
 
 The scheduler is backend-agnostic: a backend supplies segment runners and
 state plumbing.  `JaxBackend` (here) runs the pure-JAX phase-compacted
-solver; `core.distributed._ShardMapBackend` runs segments under shard_map
+solver; `ShardMapBackend` runs any such backend's segments under shard_map
 (per-shard termination *inside* segments); `kernels.ops.PallasBackend` runs
 the Pallas tile kernels.  Both levels compose: segments before column
 compaction run on the full tableau (stage "p1"), segments after it on the
@@ -42,6 +42,7 @@ from typing import Any, List, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..obs.report import report_from_counters
 from ..obs.telemetry import init_telemetry, tel_to_numpy, zeros_numpy
@@ -63,6 +64,7 @@ from .simplex import (
     simplex_step,
     tableau_elements,
 )
+from .transfer import _pad_batch, batch_spec, shard
 
 
 class CompactionState(NamedTuple):
@@ -171,7 +173,7 @@ def init_orig(backend, state, B: int):
     are deactivated so the scheduler never counts them as active.
     """
     orig = np.arange(B, dtype=np.int64)
-    B_state = int(np.asarray(backend.status_host(state)).shape[0])
+    B_state = int(state.status.size)     # the shape alone: no fetch
     if B_state > B:
         orig = np.concatenate(
             [orig, np.full(B_state - B, -1)]).astype(np.int64)
@@ -364,6 +366,14 @@ class JaxBackend:
                                         rule=self.rule)
         return state, int(it)
 
+    def segment_bodies(self) -> dict:
+        """The unjitted segment runners by stage, each ``fn(state, steps)
+        -> (state, steps run)``: what `ShardMapBackend` puts under
+        shard_map."""
+        kw = dict(m=self.m, n=self.n, tol=self.tol, rule=self.rule)
+        return {"p1": functools.partial(segment_phase1, **kw),
+                "p2": functools.partial(segment_phase2, **kw)}
+
     def run_combined(self, state, steps):
         state, it = _segment_combined_jit(state, jnp.int32(steps), m=self.m,
                                           n=self.n, tol=self.tol,
@@ -411,6 +421,57 @@ class JaxBackend:
 
     def elements_per_step(self, stage: str) -> int:
         return tableau_elements(self.m, self.n, compacted=(stage == "p2"))
+
+
+class ShardMapBackend:
+    """A scheduler backend whose segment runners (``segment_bodies``) run
+    under shard_map over ``mesh``, the batch split by `transfer.batch_spec`:
+    each chip runs its own while loop over its own LPs, and a segment's step
+    count is the largest over the chips.  Every other call — init, gathers,
+    column compaction, extraction — is the wrapped backend's own, on the
+    whole sharded state; every state leaf is batched on axis 0.  Batches
+    are padded to a multiple of the chip count (`on_mesh`)."""
+
+    def __init__(self, base, mesh):
+        self._base = base
+        self.pad_multiple = mesh.size
+        spec = batch_spec(mesh)
+        self._runners = {stage: shard(_per_shard(body), mesh, (spec, P()))
+                         for stage, body in base.segment_bodies().items()}
+
+    def __getattr__(self, name):
+        return getattr(self._base, name)
+
+    def _run(self, stage, state, steps):
+        state, it = self._runners[stage](state, jnp.int32(steps))
+        return state, int(np.max(np.asarray(it)))
+
+    def run_phase1(self, state, steps):
+        if "p1" not in self._runners:        # an engine with no phase 1
+            return self._base.run_phase1(state, steps)
+        return self._run("p1", state, steps)
+
+    def run_phase2(self, state, steps):
+        return self._run("p2", state, steps)
+
+
+def _per_shard(body):
+    """``body`` with its step count as a one-element array, so the counts
+    of all shards come back side by side."""
+    def local(state, steps):
+        state, it = body(state, steps)
+        return state, it.reshape(1)
+    return local
+
+
+def on_mesh(backend, batch: LPBatch, mesh):
+    """``backend`` and ``batch`` for a scheduled solve over ``mesh`` (none:
+    as they are): the segments under `ShardMapBackend`, the batch padded
+    with trivial LPs to a multiple of the chip count (`init_orig` retires
+    them before the first segment)."""
+    if mesh is None:
+        return backend, batch
+    return ShardMapBackend(backend, mesh), _pad_batch(batch, mesh.size)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -572,7 +633,7 @@ def solve_batched_compacted(batch: LPBatch, *, dtype=jnp.float32,
                             scale: Optional[bool] = None,
                             warm: WarmStart | None = None,
                             telemetry: bool = False,
-                            tracer=None) -> LPResult:
+                            tracer=None, mesh=None) -> LPResult:
     """Solve a batch with the two-level work-elimination engine (phase
     compaction + active-set compaction scheduler) on the pure-JAX backend.
     Accepts a GeneralLPBatch like every solver entry point (canonicalize on
@@ -593,14 +654,17 @@ def solve_batched_compacted(batch: LPBatch, *, dtype=jnp.float32,
 
     ``warm`` seeds the initial state (warm-derived leaves then ride the
     bucket gathers automatically); compacted results report ``warm=None``
-    (no terminal-state capture across the retirement buckets)."""
+    (no terminal-state capture across the retirement buckets).
+
+    ``mesh`` runs the segments under shard_map over its devices
+    (`ShardMapBackend`, ``solve_shard_map(segment_k=)``)."""
     if canonicalize_backend(backend) != "tableau":
         return resolve_backend(backend, compacted=True)(
             batch, dtype=dtype, tol=tol, feas_tol=feas_tol,
             max_iters=max_iters, segment_k=segment_k,
             compact_threshold=compact_threshold, pricing=pricing,
             stats_out=stats_out, presolve=presolve, scale=scale, warm=warm,
-            telemetry=telemetry, tracer=tracer)
+            telemetry=telemetry, tracer=tracer, mesh=mesh)
     batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale,
                                   tracer=tracer)
     m, n = batch.m, batch.n
@@ -612,17 +676,18 @@ def solve_batched_compacted(batch: LPBatch, *, dtype=jnp.float32,
         tol = 1e-6 if dtype == jnp.float32 else 1e-9
     if feas_tol is None:
         feas_tol = 1e-5 if dtype == jnp.float32 else 1e-7
-    backend = JaxBackend(m, n, tol, feas_tol, dtype, pricing=pricing)
+    backend, padded = on_mesh(
+        JaxBackend(m, n, tol, feas_tol, dtype, pricing=pricing), batch, mesh)
     with span("lp.dispatch", tracer, backend="tableau", B=batch.batch, m=m,
               n=n):
-        state = backend.init(jnp.asarray(batch.A, dtype),
-                             jnp.asarray(batch.b, dtype),
-                             jnp.asarray(batch.c, dtype),
-                             ub=jnp.asarray(batch.upper_bounds(), dtype),
+        state = backend.init(jnp.asarray(padded.A, dtype),
+                             jnp.asarray(padded.b, dtype),
+                             jnp.asarray(padded.c, dtype),
+                             ub=jnp.asarray(padded.upper_bounds(), dtype),
                              warm=prepare_warm(warm, rec, batch),
                              telemetry=telemetry)
     B = batch.batch
-    orig = np.arange(B, dtype=np.int64)
+    state, orig = init_orig(backend, state, B)
     cfg = CompactionConfig(
         segment_k=int(segment_k),
         compact_threshold=resolve_compact_threshold(compact_threshold,

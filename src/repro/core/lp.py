@@ -250,7 +250,7 @@ def backend_spec(backend: str) -> BackendSpec:
 def resolve_backend(backend: str, *, compacted: bool = False,
                     local: bool = False, sparse: bool = False):
     """Late-bound engine entry point: the monolithic batched solver, the
-    compaction-scheduled variant, the traceable pjit/shard_map body, or
+    compaction-scheduled variant, the traceable jit/shard_map body, or
     (``sparse=True``) the shared-pattern sparse solver for backends whose
     spec advertises ``supports_sparse``.  Importing lazily keeps the
     registry cycle-free (engine modules import this module)."""

@@ -59,7 +59,7 @@ final basis — backend-uniform, and mapped to original coordinates by
 ``forms.Recovery.recover_duals`` for general batches.
 
 Composition mirrors the other engines: ``solve_pdhg`` is the traceable body
-(pjit/shard_map), ``solve_batched_pdhg`` the jitted entry,
+(jit/shard_map), ``solve_batched_pdhg`` the jitted entry,
 ``solve_batched_pdhg_compacted`` runs check-rounds as scheduler segments so
 converged LPs retire into power-of-two buckets (PDHG's per-LP iteration
 counts spread far wider than simplex pivot counts — mean/max ratios of
@@ -70,7 +70,6 @@ matvec + prox + restart check in VMEM).
 from __future__ import annotations
 
 import functools
-import time
 from typing import Any, List, NamedTuple, Optional
 
 import jax
@@ -80,6 +79,7 @@ import numpy as np
 from ..obs.report import report_from_counters
 from ..obs.telemetry import init_telemetry, tel_pdhg_update, tel_to_numpy
 from .forms import ensure_canonical, finish_result, prepare_warm
+from .transfer import lp_leaves, run, sharded_solve
 from .lp import (
     INFEASIBLE,
     ITERATION_LIMIT,
@@ -584,7 +584,7 @@ def solve_pdhg(A, b, c, ub=None, *, m: int, n: int, max_iters: int,
                warm_x=None, warm_y=None, warm_omega=None,
                full_state: bool = False, step_rule: str = "fixed",
                telemetry: bool = False):
-    """Traceable whole-solve body (shared by jit, pjit and shard_map):
+    """Traceable whole-solve body (shared by jit and shard_map):
     setup + one while_loop over check rounds.  ``feas_tol`` is accepted for
     entry-point uniformity but unused (PDHG has no phase 1 — feasibility is
     part of the KKT residual).  ``warm_x``/``warm_y``/``warm_omega`` seed
@@ -680,11 +680,13 @@ def solve_batched_pdhg(batch: LPBatch, *, dtype=jnp.float32,
                        scale: bool | None = None,
                        warm: WarmStart | None = None,
                        step_rule: str = "fixed",
-                       telemetry: bool = False) -> LPResult:
+                       telemetry: bool = False,
+                       mesh=None) -> LPResult:
     """Solve a batch with the restarted-PDHG first-order engine.
 
-    Same LPBatch -> LPResult contract and GeneralLPBatch acceptance as
-    every solver entry point.  Differences from the simplex engines:
+    Same LPBatch -> LPResult contract, GeneralLPBatch acceptance and
+    ``mesh`` as every solver entry point.  Differences from the simplex
+    engines:
 
     * ``tol`` is the *relative KKT tolerance* (primal/dual infeasibility
       and duality gap); OPTIMAL is tolerance-based, objectives are accurate
@@ -709,37 +711,33 @@ def solve_batched_pdhg(batch: LPBatch, *, dtype=jnp.float32,
         max_iters = default_pdhg_max_iters(m, n)
     if tol is None:
         tol = 1e-5 if dtype == jnp.float32 else 1e-8
+    dt = jax.dtypes.canonicalize_dtype(dtype)
+    settings = dict(m=m, n=n, max_iters=int(max_iters), tol=float(tol),
+                    check_every=int(check_every), step_rule=str(step_rule),
+                    telemetry=bool(telemetry))
+    if mesh is not None:
+        return finish_result(rec, sharded_solve(
+            solve_pdhg, batch, mesh, dt, backend="pdhg", **settings))
     warm = prepare_warm(warm, rec, batch)
-    wx = wy = womega = None
+    leaves = lp_leaves(batch, dt) + [None, None, None]
     if warm is not None and warm.x is not None and warm.y is not None:
-        wx = jnp.asarray(np.nan_to_num(np.asarray(warm.x, np.float64),
-                                       posinf=0.0, neginf=0.0), dtype)
-        wy = jnp.asarray(np.nan_to_num(np.asarray(warm.y, np.float64),
-                                       posinf=0.0, neginf=0.0), dtype)
+        leaves[4] = (np.nan_to_num(np.asarray(warm.x, np.float64),
+                                   posinf=0.0, neginf=0.0), dt)
+        leaves[5] = (np.nan_to_num(np.asarray(warm.y, np.float64),
+                                   posinf=0.0, neginf=0.0), dt)
         if warm.omega is not None:
-            womega = jnp.asarray(np.asarray(warm.omega), dtype)
-    t0 = time.perf_counter()
-    out = _solve_pdhg_core_state(
-        jnp.asarray(batch.A, dtype), jnp.asarray(batch.b, dtype),
-        jnp.asarray(batch.c, dtype),
-        jnp.asarray(batch.upper_bounds(), dtype),
-        wx, wy, womega,
-        m=m, n=n, max_iters=int(max_iters),
-        tol=float(tol), check_every=int(check_every),
-        step_rule=str(step_rule), telemetry=bool(telemetry))
+            leaves[6] = (warm.omega, dt)
+    out, wall_s = run(functools.partial(_solve_pdhg_core_state, **settings),
+                      leaves, B=batch.batch, m=m, n=n)
     x, obj, status, iters, y, z, wx_t, wy_t, om_t, eta_t = out[:10]
     stats = None
     if telemetry:
-        jax.block_until_ready(out[10])
-        stats = report_from_counters(tel_to_numpy(out[10]),
-                                     wall_s=time.perf_counter() - t0,
+        stats = report_from_counters(tel_to_numpy(out[10]), wall_s=wall_s,
                                      backend="pdhg")
-    res = LPResult(x=np.asarray(x), objective=np.asarray(obj),
-                   status=np.asarray(status), iterations=np.asarray(iters),
-                   y=np.asarray(y), z=np.asarray(z),
-                   warm=WarmStart(m=m, n=n, x=np.asarray(wx_t),
-                                  y=np.asarray(wy_t), omega=np.asarray(om_t),
-                                  eta=np.asarray(eta_t)),
+    res = LPResult(x=x, objective=obj, status=status, iterations=iters,
+                   y=y, z=z,
+                   warm=WarmStart(m=m, n=n, x=wx_t, y=wy_t, omega=om_t,
+                                  eta=eta_t),
                    stats=stats)
     return finish_result(rec, res)
 
@@ -811,6 +809,12 @@ class PdhgBackend:
     def run_phase1(self, state, steps):
         return state, 0          # no phase 1: stage 1 is a no-op
 
+    def segment_bodies(self) -> dict:
+        """The unjitted segment runner of stage 2, the only stage
+        (`compaction.JaxBackend.segment_bodies`)."""
+        return {"p2": functools.partial(segment_pdhg, tol=self.tol,
+                                        check_every=self.check_every)}
+
     def run_phase2(self, state, steps):
         state, it = _segment_pdhg_jit(state, jnp.int32(steps), tol=self.tol,
                                       check_every=self.check_every)
@@ -854,11 +858,11 @@ def solve_batched_pdhg_compacted(
         stats_out: Optional[List] = None,
         presolve: bool = True, scale: Optional[bool] = None,
         warm: WarmStart | None = None, runner=None,
-        telemetry: bool = False, tracer=None) -> LPResult:
+        telemetry: bool = False, tracer=None, mesh=None) -> LPResult:
     """Restarted PDHG under the active-set compaction scheduler: K-round
     segments, power-of-two bucket gathers of still-running LPs (problem
     data, iterates, averages and restart state gathered alongside).  Same
-    contract as ``solve_batched_compacted``.
+    contract as ``solve_batched_compacted`` (``mesh`` included).
 
     Reproducibility: gathers never change an LP's own iterates, but the
     segment runner is a *different compilation* of the same rounds than
@@ -873,7 +877,7 @@ def solve_batched_pdhg_compacted(
     segments as Pallas tile kernels). A runner may return a batch-padded
     state from ``init`` (tile multiples); the padding slots are marked
     terminal here so the scheduler never counts them as active."""
-    from .compaction import (CompactionConfig, init_orig,
+    from .compaction import (CompactionConfig, init_orig, on_mesh,
                              resolve_compact_threshold, run_schedule)
 
     _check_pdhg_pricing(pricing)
@@ -890,13 +894,14 @@ def solve_batched_pdhg_compacted(
         # a handful of compaction checkpoints across the expected solve,
         # mirroring auto_segment_k's ~1/64-of-cap heuristic in round units
         segment_k = max(4, rounds // 64)
-    backend = (PdhgBackend(m, n, tol, dtype, check_every=check_every)
-               if runner is None
-               else runner(m, n, tol, dtype, check_every=check_every))
-    state = backend.init(jnp.asarray(batch.A, dtype),
-                         jnp.asarray(batch.b, dtype),
-                         jnp.asarray(batch.c, dtype),
-                         ub=jnp.asarray(batch.upper_bounds(), dtype),
+    backend, padded = on_mesh(
+        PdhgBackend(m, n, tol, dtype, check_every=check_every)
+        if runner is None
+        else runner(m, n, tol, dtype, check_every=check_every), batch, mesh)
+    state = backend.init(jnp.asarray(padded.A, dtype),
+                         jnp.asarray(padded.b, dtype),
+                         jnp.asarray(padded.c, dtype),
+                         ub=jnp.asarray(padded.upper_bounds(), dtype),
                          warm=prepare_warm(warm, rec, batch),
                          telemetry=telemetry)
     B = batch.batch

@@ -57,11 +57,11 @@ path is independent of batchmates, hence bitwise-invariant to batch
 decomposition), the eta-file slot clock and the refactor trigger are shared
 across the (local) batch — splitting a batch across shard_map shards or
 compaction buckets shifts *when* each LP's basis is refactorized and hence
-f32 rounding.  Identical batch composition (jit vs pjit) is bitwise;
+f32 rounding.  Identical batch composition is bitwise;
 different decompositions guarantee identical certificates and
 objectives/solutions to f32 tolerance (~1e-6), verified in
 tests/test_revised.py.
-``backend="revised"`` on solve_batched / solve_pjit / solve_shard_map
+``backend="revised"`` on solve_batched / solve_shard_map
 routes here; ``solve_batched_pallas(backend="revised")`` runs the revised
 tile kernel (kernels/revised_tile.py), which reuses this module's state
 builder, warm injection and pivot semantics and validates against it.
@@ -69,7 +69,6 @@ builder, warm injection and pivot semantics and validates against it.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Any, List, NamedTuple, Optional
 
 import jax
@@ -86,6 +85,8 @@ from .compaction import (
     JaxBackend,
     SegmentStat,
     auto_segment_k,
+    init_orig,
+    on_mesh,
     resolve_compact_threshold,
     run_schedule,
 )
@@ -103,6 +104,7 @@ from .lp import (
 from .pricing import (canonicalize_rule, partial_geometry,
                       partial_priced_candidates)
 from .simplex import _RUNNING, scatter_solution
+from .transfer import lp_leaves, run, sharded_solve
 
 # Pricing rules the revised backend supports.  steepest_edge needs
 # ||B^-1 a_j||^2 per candidate (O(m^2) per column without the tableau) and
@@ -597,7 +599,7 @@ def solve_revised(A, b, c, ub=None, *, m: int, n: int, max_iters: int,
                   pricing: str = "dantzig",
                   warm_basis=None, warm_at_upper=None,
                   full_state: bool = False, telemetry: bool = False):
-    """Traceable whole-solve body (shared by jit, pjit and shard_map): one
+    """Traceable whole-solve body (shared by jit and shard_map): one
     while_loop, per-LP phase switch inside the step (the revised method has
     no dead tableau columns, so there is nothing to phase-compact).
 
@@ -675,16 +677,17 @@ def solve_batched_revised(batch: LPBatch, *, dtype=jnp.float32,
                           presolve: bool = True,
                           scale: bool | None = None,
                           warm: WarmStart | None = None,
-                          telemetry: bool = False) -> LPResult:
+                          telemetry: bool = False,
+                          mesh=None) -> LPResult:
     """Solve a batch of LPs with the lockstep revised simplex.
 
     Same LPBatch -> LPResult contract, status codes and defaults as
     ``solve_batched_jax`` — including GeneralLPBatch acceptance
-    (canonicalize on ingestion, recover on the way out); ``pricing``
-    accepts "dantzig" (full pricing) or "partial" (rotating column blocks,
-    core/pricing.py).  ``refactor_period`` bounds the eta file (None
-    derives ~m/2 via `auto_refactor_period`).  ``warm`` accepts a
-    `WarmStart` from a previous solve (any basis-carrying engine): its
+    (canonicalize on ingestion, recover on the way out) and ``mesh``;
+    ``pricing`` accepts "dantzig" (full pricing) or "partial" (rotating
+    column blocks, core/pricing.py).  ``refactor_period`` bounds the eta
+    file (None derives ~m/2 via `auto_refactor_period`).  ``warm`` accepts
+    a `WarmStart` from a previous solve (any basis-carrying engine): its
     basis/at_upper leaves seed the eta-file via `inject_revised_warm`;
     the result's own ``warm`` field carries the terminal basis onward."""
     batch, rec = ensure_canonical(batch, presolve=presolve, scale=scale)
@@ -697,35 +700,33 @@ def solve_batched_revised(batch: LPBatch, *, dtype=jnp.float32,
         tol = 1e-6 if dtype == jnp.float32 else 1e-9
     if feas_tol is None:
         feas_tol = 1e-5 if dtype == jnp.float32 else 1e-7
-    warm = prepare_warm(warm, rec, batch)
-    wb = wonub = None
-    if warm is not None and warm.basis is not None:
-        wb = jnp.asarray(np.asarray(warm.basis), jnp.int32)
-        if warm.at_upper is not None:
-            wonub = jnp.asarray(np.asarray(warm.at_upper), bool)
     rule = canonicalize_revised_rule(pricing)
-    t0 = time.perf_counter()
-    out = _solve_revised_core_state(
-        jnp.asarray(batch.A, dtype), jnp.asarray(batch.b, dtype),
-        jnp.asarray(batch.c, dtype),
-        jnp.asarray(batch.upper_bounds(), dtype),
-        wb, wonub,
-        m=m, n=n, max_iters=int(max_iters),
-        tol=float(tol), feas_tol=float(feas_tol),
-        refactor_period=int(refactor_period),
-        pricing=rule, telemetry=bool(telemetry))
+    dt = jax.dtypes.canonicalize_dtype(dtype)
+    settings = dict(m=m, n=n, max_iters=int(max_iters), tol=float(tol),
+                    feas_tol=float(feas_tol),
+                    refactor_period=int(refactor_period), pricing=rule,
+                    telemetry=bool(telemetry))
+    if mesh is not None:
+        return finish_result(rec, sharded_solve(
+            solve_revised, batch, mesh, dt, backend="revised", **settings))
+    warm = prepare_warm(warm, rec, batch)
+    leaves = lp_leaves(batch, dt) + [None, None]
+    if warm is not None and warm.basis is not None:
+        leaves[4] = (warm.basis, np.dtype(np.int32))
+        if warm.at_upper is not None:
+            leaves[5] = (warm.at_upper, np.dtype(bool))
+    out, wall_s = run(
+        functools.partial(_solve_revised_core_state, **settings), leaves,
+        B=batch.batch, m=m, n=n)
     x, obj, status, iters, y, z, basis, onub = out[:8]
     stats = None
     if telemetry:
-        jax.block_until_ready(out[8])
-        stats = report_from_counters(tel_to_numpy(out[8]),
-                                     wall_s=time.perf_counter() - t0,
+        stats = report_from_counters(tel_to_numpy(out[8]), wall_s=wall_s,
                                      backend="revised")
-    res = LPResult(x=np.asarray(x), objective=np.asarray(obj),
-                   status=np.asarray(status), iterations=np.asarray(iters),
-                   y=np.asarray(y), z=np.asarray(z),
-                   warm=WarmStart(m=m, n=n, basis=np.asarray(basis),
-                                  at_upper=np.asarray(onub), pricing=rule),
+    res = LPResult(x=x, objective=obj, status=status, iterations=iters,
+                   y=y, z=z,
+                   warm=WarmStart(m=m, n=n, basis=basis, at_upper=onub,
+                                  pricing=rule),
                    stats=stats)
     return finish_result(rec, res)
 
@@ -838,6 +839,14 @@ class RevisedBackend(JaxBackend):
                 wonub, m=self.m, n=self.n, feas_tol=self.feas_tol)
         return state
 
+    def segment_bodies(self) -> dict:
+        """The unjitted segment runners by stage
+        (`compaction.JaxBackend.segment_bodies`)."""
+        kw = dict(m=self.m, n=self.n, tol=self.tol,
+                  refactor_period=self.refactor_period, rule=self.rule)
+        return {"p1": functools.partial(segment_revised_phase1, **kw),
+                "p2": functools.partial(segment_revised_phase2, **kw)}
+
     def run_phase1(self, state, steps):
         state, it = _segment_rev_p1_jit(
             state, jnp.int32(steps), m=self.m, n=self.n, tol=self.tol,
@@ -878,11 +887,12 @@ def solve_batched_revised_compacted(
         stats_out: Optional[List[SegmentStat]] = None,
         presolve: bool = True, scale: Optional[bool] = None,
         warm: WarmStart | None = None,
-        telemetry: bool = False, tracer=None) -> LPResult:
+        telemetry: bool = False, tracer=None, mesh=None) -> LPResult:
     """Revised simplex under the active-set compaction scheduler: K-pivot
     segments, power-of-two bucket gathers of survivors (eta file, LU factors
     and basis arrays gathered alongside), refactorization after every gather.
-    Same contract as ``solve_batched_compacted`` (GeneralLPBatch accepted).
+    Same contract as ``solve_batched_compacted`` (GeneralLPBatch and
+    ``mesh`` accepted).
     ``warm`` seeds the initial state (the warm-derived leaves then ride the
     bucket gathers automatically); the compacted result reports
     ``warm=None``."""
@@ -897,16 +907,17 @@ def solve_batched_revised_compacted(
         tol = 1e-6 if dtype == jnp.float32 else 1e-9
     if feas_tol is None:
         feas_tol = 1e-5 if dtype == jnp.float32 else 1e-7
-    backend = RevisedBackend(m, n, tol, feas_tol, dtype, pricing=pricing,
-                             refactor_period=refactor_period)
-    state = backend.init(jnp.asarray(batch.A, dtype),
-                         jnp.asarray(batch.b, dtype),
-                         jnp.asarray(batch.c, dtype),
-                         ub=jnp.asarray(batch.upper_bounds(), dtype),
+    backend, padded = on_mesh(
+        RevisedBackend(m, n, tol, feas_tol, dtype, pricing=pricing,
+                       refactor_period=refactor_period), batch, mesh)
+    state = backend.init(jnp.asarray(padded.A, dtype),
+                         jnp.asarray(padded.b, dtype),
+                         jnp.asarray(padded.c, dtype),
+                         ub=jnp.asarray(padded.upper_bounds(), dtype),
                          warm=prepare_warm(warm, rec, batch),
                          telemetry=telemetry)
     B = batch.batch
-    orig = np.arange(B, dtype=np.int64)
+    state, orig = init_orig(backend, state, B)
     cfg = CompactionConfig(
         segment_k=int(segment_k),
         compact_threshold=resolve_compact_threshold(

@@ -62,14 +62,11 @@ device lanes.
 
 All LPs in the batch share one static tableau shape per loop (see
 core/lp.py), so each loop is a single XLA computation: no host round-trips,
-no dynamic shapes, shardable over any mesh axis with pjit/shard_map.
+no dynamic shapes, shardable over any mesh axis with shard_map.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
-import threading
-import time
 from typing import Any, NamedTuple
 
 import jax
@@ -78,7 +75,6 @@ import numpy as np
 
 from ..obs.report import report_from_counters
 from ..obs.telemetry import init_telemetry, tel_simplex_update, tel_to_numpy
-from ..obs.trace import span
 from .forms import ensure_canonical, finish_result, prepare_warm
 from .lp import (
     BIG,
@@ -101,6 +97,7 @@ from .pricing import (
     select_entering,
     update_weights,
 )
+from .transfer import lp_leaves, run, sharded_solve
 
 _RUNNING = -1
 
@@ -614,8 +611,8 @@ def solve_two_phase(A, b, c, ub=None, *, m: int, n: int, max_iters: int,
                     pricing: str = "dantzig",
                     warm_basis=None, warm_at_upper=None, warm_weights=None,
                     full_state: bool = False, telemetry: bool = False):
-    """Traceable two-phase solve body, shared by jit (`_solve_core`), pjit and
-    shard_map (core/distributed.py).
+    """Traceable two-phase solve body, shared by jit (`_solve_core`) and
+    shard_map (`transfer.sharded_solve`).
 
     phase_compaction=True (default): loop 1 on the full tableau until no LP
     is still in phase 1, then `compact_tableau`, then loop 2 on the small
@@ -766,14 +763,18 @@ def solve_batched_jax(batch: LPBatch, *, dtype=jnp.float32, tol: float | None = 
                       presolve: bool = True,
                       scale: bool | None = None,
                       warm: WarmStart | None = None,
-                      telemetry: bool = False) -> LPResult:
+                      telemetry: bool = False,
+                      mesh=None) -> LPResult:
     """Solve a batch of LPs with the lockstep pure-JAX simplex.
 
     Phase-compacted by default (identical pivot sequence, ~35-50% fewer
     tableau elements per phase-2 pivot); ``phase_compaction=False`` restores
-    the paper-faithful single-loop solver.  For per-shard termination across
-    a mesh use core.distributed.solve_shard_map; for active-set compaction
-    (retiring finished LPs mid-solve) use core.compaction.
+    the paper-faithful single-loop solver.  ``mesh`` spreads the batch over
+    its devices, each with its own while loop (`transfer.sharded_solve`;
+    ``solve_batched(mesh=)`` and core.distributed.solve_shard_map plan
+    chunks for it); a mesh solve takes no ``warm`` and captures none.  For
+    active-set compaction (retiring finished LPs mid-solve) use
+    core.compaction.
     ``pricing`` selects the entering-column rule — "dantzig" (paper default),
     "steepest_edge", "devex" or "partial" (core/pricing.py); better rules
     trade a cheaper pivot *count* against a slightly costlier pivot.
@@ -801,11 +802,10 @@ def solve_batched_jax(batch: LPBatch, *, dtype=jnp.float32, tol: float | None = 
         solver = resolve_backend(backend)
         kwargs = dict(dtype=dtype, tol=tol, feas_tol=feas_tol,
                       max_iters=max_iters, pricing=pricing, warm=warm,
-                      telemetry=telemetry)
+                      telemetry=telemetry, mesh=mesh)
         if backend == "revised":
             kwargs["refactor_period"] = refactor_period
         return finish_result(rec, solver(batch, **kwargs))
-    warm = prepare_warm(warm, rec, batch)
     m, n = batch.m, batch.n
     if max_iters is None:
         max_iters = default_max_iters(m, n)
@@ -815,164 +815,38 @@ def solve_batched_jax(batch: LPBatch, *, dtype=jnp.float32, tol: float | None = 
         feas_tol = 1e-5 if dtype == jnp.float32 else 1e-7
     rule = canonicalize_rule(pricing)
     dt = jax.dtypes.canonicalize_dtype(dtype)
-    with _HOST_STAGING.lease() as cast:
-        with span("lp.h2d") as h2d:
-            with span("lp.h2d.cast") as cast_span:
-                leaves = {"A": (batch.A, dt), "b": (batch.b, dt),
-                          "c": (batch.c, dt), "ub": (_upper_bounds(batch), dt)}
-                if warm is not None and warm.basis is not None:
-                    leaves["wb"] = (warm.basis, np.dtype(np.int32))
-                    if warm.at_upper is not None:
-                        leaves["wfl"] = (warm.at_upper, np.dtype(bool))
-                    # carried weights are only meaningful for devex (its
-                    # reference framework is cross-solve state); steepest
-                    # edge re-initializes exactly from the warm tableau,
-                    # dantzig/partial never read them
-                    if (rule == "devex" and warm.pricing == rule
-                            and warm.weights is not None
-                            and np.asarray(warm.weights).shape[1] >= n + m):
-                        leaves["ww"] = (warm.weights, dt)
-                host, cast_args = cast(list(leaves.values()))
-                host = dict(zip(leaves, host))
-                cast_span.set(**cast_args)
-            with span("lp.h2d.put"):
-                dev = {k: _put(leaves[k][0], h, leaves[k][1])
-                       for k, h in host.items()}
-            h2d.set(bytes_in=sum(getattr(a, "nbytes", 0) for a, _ in
-                                 leaves.values()),
-                    bytes_out=sum(h.nbytes for h in host.values()))
-        # free the host copies now, as jnp.asarray frees its temporaries
-        del leaves, host
-        t0 = time.perf_counter()
-        with span("lp.dispatch", B=batch.batch, m=m, n=n):
-            out = _solve_core_state(
-                dev["A"], dev["b"], dev["c"], dev["ub"], dev.get("wb"),
-                dev.get("wfl"), dev.get("ww"),
-                m=m, n=n, max_iters=int(max_iters), tol=float(tol),
-                feas_tol=float(feas_tol),
-                phase_compaction=bool(phase_compaction),
-                pricing=rule, telemetry=bool(telemetry))
-        with span("lp.wait"):
-            jax.block_until_ready(out)
-        # the outputs are ready, so every input they read has been read:
-        # the staging buffer may take the next call's inputs
-        del dev
-    wall_s = time.perf_counter() - t0
-    with span("lp.d2h") as d2h:
-        fetched = [np.asarray(a) for a in out[:9]]
-        stats = None
-        if telemetry:
-            counters = tel_to_numpy(out[9])
-            stats = report_from_counters(counters, wall_s=wall_s,
-                                         backend="tableau")
-            fetched += counters.values()
-        d2h.set(arrays=len(fetched), bytes=sum(a.nbytes for a in fetched))
-    x, obj, status, iters, y, z, basis, flip, w = fetched[:9]
+    settings = dict(m=m, n=n, max_iters=int(max_iters), tol=float(tol),
+                    feas_tol=float(feas_tol),
+                    phase_compaction=bool(phase_compaction), pricing=rule,
+                    telemetry=bool(telemetry))
+    if mesh is not None:
+        return finish_result(rec, sharded_solve(
+            solve_two_phase, batch, mesh, dt, backend="tableau", **settings))
+    warm = prepare_warm(warm, rec, batch)
+    leaves = lp_leaves(batch, dt) + [None, None, None]
+    if warm is not None and warm.basis is not None:
+        leaves[4] = (warm.basis, np.dtype(np.int32))
+        if warm.at_upper is not None:
+            leaves[5] = (warm.at_upper, np.dtype(bool))
+        # carried weights are only meaningful for devex (its reference
+        # framework is cross-solve state); steepest edge re-initializes
+        # exactly from the warm tableau, dantzig/partial never read them
+        if (rule == "devex" and warm.pricing == rule
+                and warm.weights is not None
+                and np.asarray(warm.weights).shape[1] >= n + m):
+            leaves[6] = (warm.weights, dt)
+    out, wall_s = run(functools.partial(_solve_core_state, **settings),
+                      leaves, B=batch.batch, m=m, n=n)
+    x, obj, status, iters, y, z, basis, flip, w = out[:9]
+    stats = None
+    if telemetry:
+        stats = report_from_counters(tel_to_numpy(out[9]), wall_s=wall_s,
+                                     backend="tableau")
     capture = WarmStart(m=m, n=n, basis=basis, at_upper=flip, weights=w,
                         pricing=rule)
     res = LPResult(x=x, objective=obj, status=status, iterations=iters,
                    y=y, z=z, warm=capture, stats=stats)
     return finish_result(rec, res)
-
-
-def _host_cast(a, dtype):
-    """The host half of ``jnp.asarray(a, dtype)``: NumPy's cast of host
-    input (the input itself where it already has ``dtype``); device
-    arrays pass through."""
-    return a if isinstance(a, jax.Array) else np.asarray(a, dtype=dtype)
-
-
-def _upper_bounds(batch: LPBatch) -> np.ndarray:
-    """``batch.upper_bounds()`` without building its float64 +inf array
-    when the batch has no bounds: a read-only broadcast of +inf with the
-    same shape, dtype and ``nbytes``, which the cast fills from."""
-    if batch.ub is None:
-        return np.broadcast_to(np.inf, (batch.batch, batch.n))
-    return batch.upper_bounds()
-
-
-_ALIGN = 64   # bytes: every staged array starts on a cache line
-
-
-def _cast_fresh(pairs):
-    """The cast of `HostStaging.lease` into fresh memory: `_host_cast`."""
-    return ([_host_cast(a, t) for a, t in pairs],
-            {"staging": "fresh", "staged_bytes": 0})
-
-
-class HostStaging:
-    """One host buffer, kept across calls, into which the solve path casts
-    its inputs before it puts them on the device.
-
-    Cast into a fresh array, the float32 copy of a large float64 batch is
-    mapped anew on every call, and its first-touch page faults cost several
-    times the conversion itself (the 314 MB A block of 100,000 28x28 LPs).
-    Cast into this buffer, a call no larger than an earlier one writes into
-    pages that are already there.  The buffer grows to the largest call's
-    cast output and keeps that much host memory for the life of the
-    process: the size each such call used to allocate for itself.
-    """
-
-    def __init__(self):
-        self._buf = np.empty(0, np.uint8)
-        self._lock = threading.Lock()
-
-    @contextlib.contextmanager
-    def lease(self):
-        """The buffer for one call.  Yields ``cast``: ``cast(pairs)`` of
-        ``(array, dtype)`` pairs returns the host arrays `_host_cast` would
-        (byte for byte) and the ``lp.h2d.cast`` args ``staging``
-        (``"reused"``, ``"grown"``, or ``"fresh"`` where another call holds
-        the lease and the cast goes to fresh memory) and ``staged_bytes``.
-        Hold the lease until the call's outputs are ready and its device
-        inputs dropped: until then a transfer may still read the buffer
-        (on the CPU a device array may be the buffer itself)."""
-        if not self._lock.acquire(blocking=False):
-            yield _cast_fresh
-            return
-        try:
-            yield self._cast
-        finally:
-            self._lock.release()
-
-    def _cast(self, pairs):
-        # a host array of another dtype is staged; an array that has the
-        # dtype, a device array or a list keeps `_host_cast`
-        slots, end = [], 0
-        for a, t in pairs:
-            if isinstance(a, np.ndarray) and a.dtype != t:
-                size = a.size * t.itemsize
-                slots.append((end, size))
-                end += -(-size // _ALIGN) * _ALIGN
-            else:
-                slots.append(None)
-        start = -self._buf.ctypes.data % _ALIGN
-        staging = "reused"
-        if end and start + end > self._buf.size:
-            self._buf = np.empty(end + _ALIGN, np.uint8)
-            start, staging = -self._buf.ctypes.data % _ALIGN, "grown"
-        host = []
-        for (a, t), slot in zip(pairs, slots):
-            if slot is None:
-                host.append(_host_cast(a, t))
-                continue
-            off, size = start + slot[0], slot[1]
-            out = self._buf[off:off + size].view(t).reshape(a.shape)
-            np.copyto(out, a, casting="unsafe")
-            host.append(out)
-        staged = sum(slot[1] for slot in slots if slot is not None)
-        return host, {"staging": staging, "staged_bytes": staged}
-
-
-_HOST_STAGING = HostStaging()
-
-
-def _put(a, host, dtype):
-    """The rest of ``jnp.asarray(a, dtype)`` once ``host = _host_cast(a,
-    dtype)``: the same calls, so the same transfer and device ops."""
-    if host is a:
-        return jnp.asarray(a, dtype)
-    return jax.lax.convert_element_type(host, dtype)
 
 
 def flops_per_pivot(m: int, n: int, compacted: bool = False) -> int:

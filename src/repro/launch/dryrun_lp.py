@@ -2,13 +2,9 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Dry-run of the paper's own workload on the production mesh: batches of
-LPs sharded over all 256/512 chips, in both distribution modes:
-
-  * pjit      — lockstep global while-loop (paper-faithful: every pivot is
-                synchronized across the whole batch; the loop condition is a
-                cross-chip all-reduce)
-  * shard_map — per-chip termination (the TPU analogue of the paper's
-                per-block early exit; zero cross-chip collectives)
+LPs sharded over all 256/512 chips, as ``solve_shard_map`` runs them:
+per-chip termination (each chip its own while loop, the TPU analogue of
+the paper's per-block early exit; zero cross-chip collectives).
 
 The simplex while-loop has no static trip count, so the HLO cost model takes
 default_trip = the oracle-measured mean pivot count for the workload class.
@@ -25,7 +21,7 @@ def main():
     import jax
     from repro.configs.paper_lp import WORKLOADS, build_batch
     from repro.core import LPBatch, canonical_shape, solve_batched_reference
-    from repro.core.distributed import solve_pjit, solve_shard_map
+    from repro.core.distributed import solve_shard_map
     from repro.launch.mesh import make_production_mesh
     from repro.analysis.hlo_cost import module_cost
     from repro.core.simplex import flops_per_pivot
@@ -60,33 +56,32 @@ def main():
                "batch": wl.batch, "m": wl.m, "n": wl.n,
                "m_device": m_dev, "n_device": n_dev,
                "mean_pivots": mean_pivots}
-        for mode, solver in (("pjit", solve_pjit),
-                             ("shard_map", solve_shard_map)):
-            with mesh:
-                lowered = solver(batch, mesh, lower_only=True)
-                compiled = lowered.compile()
-            txt = compiled.as_text()
-            cost = module_cost(txt, default_trip=mean_pivots)
-            ana = flops_per_pivot(m_dev, n_dev) * mean_pivots * wl.batch / chips
-            mem = compiled.memory_analysis()
-            rec[mode] = {
-                "flops_per_dev": cost["flops"],
-                "analytic_flops_per_dev": ana,
-                "mem_bytes_per_dev": cost["mem_bytes"],
-                "collective_bytes_per_dev":
-                    cost["collectives"]["_total"]["bytes"],
-                "collective_count": cost["collectives"]["_total"]["count"],
-                "temp_bytes": int(getattr(mem, "temp_size_in_bytes", 0)),
-                "compute_s": cost["flops"] / 197e12,
-                "memory_s": cost["mem_bytes"] / 819e9,
-                "collective_s":
-                    cost["collectives"]["_total"]["bytes"] / 50e9,
-            }
-            print(f"[dryrun-lp] {wl.name} {mesh_name} {mode}: "
-                  f"pivots~{mean_pivots:.0f} "
-                  f"flops/dev={rec[mode]['flops_per_dev']:.2e} "
-                  f"collB/dev={rec[mode]['collective_bytes_per_dev']:.2e} "
-                  f"coll#={rec[mode]['collective_count']:.0f}")
+        mode = "shard_map"
+        with mesh:
+            lowered = solve_shard_map(batch, mesh, lower_only=True)
+            compiled = lowered.compile()
+        txt = compiled.as_text()
+        cost = module_cost(txt, default_trip=mean_pivots)
+        ana = flops_per_pivot(m_dev, n_dev) * mean_pivots * wl.batch / chips
+        mem = compiled.memory_analysis()
+        rec[mode] = {
+            "flops_per_dev": cost["flops"],
+            "analytic_flops_per_dev": ana,
+            "mem_bytes_per_dev": cost["mem_bytes"],
+            "collective_bytes_per_dev":
+                cost["collectives"]["_total"]["bytes"],
+            "collective_count": cost["collectives"]["_total"]["count"],
+            "temp_bytes": int(getattr(mem, "temp_size_in_bytes", 0)),
+            "compute_s": cost["flops"] / 197e12,
+            "memory_s": cost["mem_bytes"] / 819e9,
+            "collective_s":
+                cost["collectives"]["_total"]["bytes"] / 50e9,
+        }
+        print(f"[dryrun-lp] {wl.name} {mesh_name} {mode}: "
+              f"pivots~{mean_pivots:.0f} "
+              f"flops/dev={rec[mode]['flops_per_dev']:.2e} "
+              f"collB/dev={rec[mode]['collective_bytes_per_dev']:.2e} "
+              f"coll#={rec[mode]['collective_count']:.0f}")
         with open(os.path.join(args.out,
                                f"{wl.name}__{mesh_name}.json"), "w") as f:
             json.dump(rec, f, indent=1)

@@ -25,19 +25,17 @@ def test_lp_solvers_sharded_match_reference():
     out = _run("""
         import numpy as np
         from repro.core import (OPTIMAL, random_lp_batch,
-                                solve_batched_reference, solve_pjit,
-                                solve_shard_map)
+                                solve_batched_reference, solve_shard_map)
         from repro.distributed.sharding import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(0)
         batch = random_lp_batch(rng, B=37, m=12, n=8, feasible_start=False)
         ref = solve_batched_reference(batch)
-        for solver in (solve_pjit, solve_shard_map):
-            res = solver(batch, mesh)
-            ok = (ref.status == OPTIMAL) & (res.status == OPTIMAL)
-            assert (ref.status == res.status).mean() >= 0.95, solver
-            rel = abs(ref.objective[ok] - res.objective[ok]) / abs(ref.objective[ok])
-            assert rel.max() < 5e-4, solver
+        res = solve_shard_map(batch, mesh)
+        ok = (ref.status == OPTIMAL) & (res.status == OPTIMAL)
+        assert (ref.status == res.status).mean() >= 0.95
+        rel = abs(ref.objective[ok] - res.objective[ok]) / abs(ref.objective[ok])
+        assert rel.max() < 5e-4
         print("LP-OK")
     """)
     assert "LP-OK" in out

@@ -369,19 +369,18 @@ def test_solve_batched_chunked_general():
 
 
 def test_general_through_distributed_and_pallas():
-    """The remaining entry points accept GeneralLPBatch directly: pjit,
-    shard_map (one-shot and segmented) and the Pallas kernel all report in
-    original coordinates."""
+    """The remaining entry points accept GeneralLPBatch directly: shard_map
+    (one-shot and segmented) and the Pallas kernel all report in original
+    coordinates."""
     import jax
     from jax.sharding import Mesh
-    from repro.core import solve_pjit, solve_shard_map
+    from repro.core import solve_shard_map
     from repro.kernels.ops import solve_batched_pallas
 
     g = _general(B=8, m=5, n=5, eq_frac=0.3)
     ref = solve_batched_reference(g)
     mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
     outs = {
-        "pjit": solve_pjit(g, mesh),
         "shard_map": solve_shard_map(g, mesh),
         "shard_map_seg": solve_shard_map(g, mesh, segment_k=8),
         "pallas": solve_batched_pallas(g),

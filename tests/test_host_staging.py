@@ -1,6 +1,6 @@
-"""The host staging buffer (`simplex.HostStaging`): the solve path casts its
+"""The host staging buffer (`transfer.HostStaging`): the solve path casts its
 inputs into one buffer kept across calls, byte for byte as the fresh cast
-(`simplex._host_cast`) would, and says on ``lp.h2d.cast`` whether it
+(`transfer._host_cast`) would, and says on ``lp.h2d.cast`` whether it
 reused the buffer, grew it, or cast into fresh memory because another call
 held it."""
 import threading
@@ -9,8 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import LPBatch, random_lp_batch, simplex, solve_batched_jax
-from repro.core.simplex import HostStaging, _host_cast, _upper_bounds
+from repro.core import (LPBatch, random_lp_batch, simplex, solve_batched_jax,
+                        transfer)
+from repro.core.transfer import HostStaging, _host_cast, _upper_bounds
 from repro.obs import SpanTracer
 
 F32, I32 = np.dtype(np.float32), np.dtype(np.int32)
@@ -74,20 +75,20 @@ def test_staged_cast_is_the_fresh_cast(case):
 FIELDS = ("x", "objective", "status", "iterations", "y", "z")
 
 
-def _solve(batch):
+def _solve(batch, backend="tableau"):
     """The result and the ``lp.h2d.cast`` args of one solve."""
     tracer = SpanTracer()
     with tracer.active():
-        res = solve_batched_jax(batch)
+        res = solve_batched_jax(batch, backend=backend)
     (cast,) = [s for root in tracer.roots for s in root.walk()
                if s.name == "lp.h2d.cast"]
     return res, cast.args
 
 
-def _fresh(batch):
+def _fresh(batch, backend):
     """The result of the fresh cast: the buffer held by another call."""
-    with simplex._HOST_STAGING.lease():
-        res, args = _solve(batch)
+    with transfer._HOST_STAGING.lease():
+        res, args = _solve(batch, backend)
     assert args == {"staging": "fresh", "staged_bytes": 0}
     return res
 
@@ -130,26 +131,29 @@ def _two_threads(batches, monkeypatch):
 
 
 SCENARIOS = {
-    # (batch sizes in call order, the staging each call reads)
-    "same_shape": ((24, 24), ["grown", "reused"]),
-    "smaller_after_larger": ((40, 16), ["grown", "reused"]),
-    "two_threads": ((24, 24), ["grown", "fresh"]),
+    # (batch sizes in call order, the staging each call reads, the engine)
+    "same_shape": ((24, 24), ["grown", "reused"], "tableau"),
+    "smaller_after_larger": ((40, 16), ["grown", "reused"], "tableau"),
+    "two_threads": ((24, 24), ["grown", "fresh"], "tableau"),
+    "revised_smaller_after_larger": ((40, 16), ["grown", "reused"],
+                                     "revised"),
+    "pdhg_smaller_after_larger": ((40, 16), ["grown", "reused"], "pdhg"),
 }
 
 
 @pytest.mark.parametrize("scenario", sorted(SCENARIOS))
 def test_solve_stages_its_cast(scenario, monkeypatch):
-    sizes, want = SCENARIOS[scenario]
+    sizes, want, backend = SCENARIOS[scenario]
     batches = [random_lp_batch(np.random.default_rng(20 + k), B, 6, 5)
                for k, B in enumerate(sizes)]
-    refs = [_fresh(b) for b in batches]
-    monkeypatch.setattr(simplex, "_HOST_STAGING", HostStaging())
+    refs = [_fresh(b, backend) for b in batches]
+    monkeypatch.setattr(transfer, "_HOST_STAGING", HostStaging())
     if scenario == "two_threads":
         out = _two_threads(batches, monkeypatch)
     else:
         out = []
         for b in batches:
-            out.append(_solve(b))
+            out.append(_solve(b, backend))
             if len(out) == 1:
                 kept = {f: getattr(out[0][0], f).copy() for f in FIELDS}
         # the second call leaves the first call's result as it was

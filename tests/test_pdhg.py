@@ -32,7 +32,7 @@ from repro.kernels.ops import solve_batched_pallas
 TOL = 1e-5          # the engine's f32 default
 CHECK = 1e-3        # assertion budget: ~2 decades above TOL
 # Cross-executor agreement budget: two different compilations (jit vs
-# segment-jit vs pjit) of the same rounds fuse differently in f32, so the
+# segment-jit vs shard_map) of the same rounds fuse differently in f32, so the
 # restart trajectories — and the tol-satisfying points they stop at — drift
 # apart by ~feasibility-slack x multiplier scale.  1e-3 relative is the
 # honest contract for a tolerance-based engine (cf. the revised backend's
@@ -235,20 +235,19 @@ def test_chunked_driver_and_sorting():
 
 
 def test_distributed_entry_points():
-    from repro.core import solve_pjit, solve_shard_map
+    from repro.core import solve_shard_map
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
     batch = random_lp_batch(_rng(16), 6, 6, 6)
     mono = solve_batched_pdhg(batch)
-    pj = solve_pjit(batch, mesh, backend="pdhg")
     sm = solve_shard_map(batch, mesh, backend="pdhg")
     seg = solve_shard_map(batch, mesh, backend="pdhg", segment_k=8)
-    for r in (pj, sm, seg):
+    for r in (sm, seg):
         assert (r.status == mono.status).all()
         ok = mono.status == OPTIMAL
         np.testing.assert_allclose(r.objective[ok], mono.objective[ok],
                                    rtol=XTOL, atol=XTOL)
-    assert pj.y is not None and seg.y is not None
+    assert sm.y is not None and seg.y is not None
 
 
 def test_pallas_interpret_parity():
@@ -292,13 +291,12 @@ def test_fixture_pdhg_through_every_entry_point():
     gb = perturbed_batch(g, 4, np.random.default_rng(1))
     ref = solve_batched_reference(gb)
     mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
-    from repro.core import solve_pjit, solve_shard_map
+    from repro.core import solve_shard_map
 
     paths = {
         "jax": solve_batched_jax(gb, backend="pdhg"),
         "batched": solve_batched(gb, backend="pdhg"),
         "compacted": solve_batched_compacted(gb, backend="pdhg"),
-        "pjit": solve_pjit(gb, mesh, backend="pdhg"),
         "shard_map": solve_shard_map(gb, mesh, backend="pdhg"),
         "pallas": solve_batched_pallas(gb, backend="pdhg"),
     }

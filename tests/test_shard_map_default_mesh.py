@@ -69,6 +69,12 @@ def test_no_mesh_matches_float64_reference():
 def test_no_mesh_is_bitwise_one_device_solve_batched():
     out = _run("""
         same(solve_shard_map(batch), solve_batched(batch))
+        # float64: the mesh takes the engine's float64 tolerances too
+        import jax.numpy as jnp
+        jax.config.update("jax_enable_x64", True)
+        wide = solve_shard_map(batch, dtype=jnp.float64)
+        assert wide.x.dtype == np.float64
+        same(wide, solve_batched(batch, dtype=jnp.float64))
         print("BITWISE-OK")
     """)
     assert "BITWISE-OK" in out
@@ -142,34 +148,45 @@ def test_spans_and_shard_max_iters():
 
 def test_shards_cast_into_the_kept_buffer():
     out = _run("""
-        from repro.core import simplex
-        from repro.core.distributed import _solve_shards, make_mesh
+        import functools
+        from repro.core import transfer
+        from repro.core.distributed import make_mesh
+        from repro.core.simplex import solve_two_phase
         from repro.obs import SpanTracer
         mesh = make_mesh()
+        spec = transfer.batch_spec(mesh)
+        program = transfer.shard(functools.partial(
+            solve_two_phase, m=12, n=8, max_iters=250, tol=1e-6,
+            feas_tol=1e-5), mesh, (spec,) * 4)
         other = random_lp_batch(np.random.default_rng(4), B=37, m=12, n=8,
                                 feasible_start=False)
 
         def solve(b):
+            padded, _ = transfer._pad_batch(b, mesh.size)
             tr = SpanTracer()
             with tr.active():
-                res = _solve_shards(b, mesh=mesh)
+                out, _ = transfer.run(
+                    program, transfer.lp_leaves(padded, np.dtype(np.float32)),
+                    mesh=mesh, B=padded.batch, m=12, n=8)
             (cast,) = [s for root in tr.roots for s in root.walk()
                        if s.name == "lp.h2d.cast"]
-            return res, cast.args["staging"]
+            return out, cast.args["staging"]
+
+        def equal(a, b):
+            for u, v in zip(a, b):
+                assert np.array_equal(u, v, equal_nan=True)
 
         first, s1 = solve(batch)
-        kept = {f: getattr(first, f).copy() for f in FIELDS}
+        kept = [a.copy() for a in first]
         second, s2 = solve(other)
         assert (s1, s2) == ("grown", "reused"), (s1, s2)
-        for f in FIELDS:      # the second call left the first result alone
-            assert np.array_equal(getattr(first, f), kept[f],
-                                  equal_nan=True), f
+        equal(first, kept)    # the second call left the first result alone
         # the fresh cast, as before the buffer: another call holds it
-        with simplex._HOST_STAGING.lease():
+        with transfer._HOST_STAGING.lease():
             (f1, t1), (f2, t2) = solve(batch), solve(other)
         assert (t1, t2) == ("fresh", "fresh"), (t1, t2)
-        same(first, f1)
-        same(second, f2)
+        equal(first, f1)
+        equal(second, f2)
         print("STAGING-OK")
     """)
     assert "STAGING-OK" in out
